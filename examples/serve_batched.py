@@ -20,6 +20,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as tr
 from repro.serve import SamplingParams, ServeEngine, ServeSettings
 
@@ -35,6 +36,7 @@ def main():
     ap.add_argument("--ckpt", default=None,
                     help="serve a launch/train.py --save artifact")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch).smoke()
     settings = ServeSettings(max_concurrency=args.concurrency,

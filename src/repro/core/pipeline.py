@@ -182,15 +182,6 @@ class CohortSample:
 
 
 # ======================================================== kernel plumbing
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
-def _largest_divisor(n: int, cap: int) -> int:
-    from repro.kernels.common import largest_divisor
-    return largest_divisor(n, cap)
-
-
 def _seed_of(key: jax.Array) -> jax.Array:
     return jax.random.bits(key, dtype=jnp.uint32)
 
@@ -283,19 +274,13 @@ class DSCCompress(CompressStage):
         # mirror Int8RoundTrip's key discipline: one subkey for the inner
         # RandP draw, one for the rounding draw
         k_in, k_q = jax.random.split(key)
-        nb = g.shape[0] // q_kernel.QBLOCK
         q, scale, s_new = dq_kernel.dsc_quantize(
             g, s, _seed_of(k_in), _seed_of(k_q), p=inner.p,
-            gamma=self.gamma,
-            block_b=_largest_divisor(nb, dq_kernel.BLOCK_B),
-            interpret=_interpret())
+            gamma=self.gamma)
         # the simulator aggregates in f32, so reconstruct the wire value
         # (the distributed runtime ships q/scale and dequantizes receiver
         # side instead)
-        v_hat = q_kernel.dequantize(q, scale,
-                                    block_b=_largest_divisor(
-                                        nb, q_kernel.BLOCK_B),
-                                    interpret=_interpret())
+        v_hat = q_kernel.dequantize(q, scale)
         shape = (K, n + pad)
         return (v_hat.reshape(shape)[:, :n],
                 s_new.reshape(shape)[:, :n])
@@ -309,11 +294,8 @@ class DSCCompress(CompressStage):
         pad = (-n) % dsc_kernel.LANES
         g = jnp.pad(grads, ((0, 0), (0, pad))).reshape(-1)
         s = jnp.pad(s_clients, ((0, 0), (0, pad))).reshape(-1)
-        rows = g.shape[0] // dsc_kernel.LANES
         v, s_new = dsc_kernel.dsc_update(
-            g, s, _seed_of(key), p=self.compressor.p, gamma=self.gamma,
-            block_rows=_largest_divisor(rows, dsc_kernel.BLOCK_ROWS),
-            interpret=_interpret())
+            g, s, _seed_of(key), p=self.compressor.p, gamma=self.gamma)
         shape = (K, n + pad)
         return v.reshape(shape)[:, :n], s_new.reshape(shape)[:, :n]
 
@@ -367,12 +349,8 @@ class Int8Wire(CompressStage):
         K, n = v.shape
         pad = (-n) % q_kernel.QBLOCK
         flat = jnp.pad(v, ((0, 0), (0, pad))).reshape(-1)
-        nb = flat.shape[0] // q_kernel.QBLOCK
-        block_b = _largest_divisor(nb, q_kernel.BLOCK_B)
-        q, scale = q_kernel.quantize(flat, _seed_of(self._key(keys)),
-                                     block_b=block_b, interpret=_interpret())
-        deq = q_kernel.dequantize(q, scale, block_b=block_b,
-                                  interpret=_interpret())
+        q, scale = q_kernel.quantize(flat, _seed_of(self._key(keys)))
+        deq = q_kernel.dequantize(q, scale)
         return deq.reshape(K, n + pad)[:, :n], state
 
 

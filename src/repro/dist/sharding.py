@@ -40,6 +40,13 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 QBLOCK = 256        # coords per int8-wire scale (kernels/quantize.QBLOCK)
 
 
+def shard_map(f, mesh: Mesh, in_specs, out_specs):
+    """Fully-manual ``jax.shard_map`` (every mesh axis manual) — the one
+    form the train step and the serving engine's decode body use."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
+
+
 # ------------------------------------------------------------------ axes
 def client_axes(mesh: Mesh) -> tuple[str, ...]:
     """Mesh axes enumerating FSA aggregators (everything but the

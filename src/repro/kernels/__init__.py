@@ -1,3 +1,4 @@
-"""Pallas TPU kernels for the compute hot spots (validated interpret=True
-on CPU): fused DSC update, int8 wire quantization, flash attention."""
-from repro.kernels import ops  # noqa: F401
+"""Pallas TPU kernels for the compute hot spots: fused DSC update, int8
+wire quantization, the fused DSC->int8 wire step, flash attention and
+paged decode attention.  ``common.interpret_mode`` decides at call time
+whether they compile for the TPU or run in interpret mode on the CPU."""

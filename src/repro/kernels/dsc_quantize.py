@@ -15,8 +15,9 @@ per-round client hot loop, and the shift state sees precisely what
 crosses the wire (the Int8RoundTrip composition of Definition 3.1
 omega-compressors, so Theorem 3.2's contraction bookkeeping still holds).
 
-Tiling: flat vector viewed as (n_blocks, 256); each grid step handles a
-(BLOCK_B, 256) tile.  Both RNG draws are counter-based (murmur3 on the
+Tiling: flat vector viewed as (n_blocks, 256), padded to whole row
+blocks; each grid step handles a (BLOCK_B, 256) tile and writes its
+scales into a lane-dense (1, n_blocks) row.  Both RNG draws are counter-based (murmur3 on the
 global flat element index), identical to `ref.dsc_quantize_ref`.
 """
 from __future__ import annotations
@@ -27,7 +28,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import largest_divisor, uniform_from_index
+from repro.kernels.common import (interpret_mode, pad_rows, row_blocks,
+                                  uniform_from_index)
 from repro.kernels.quantize import QBLOCK
 
 BLOCK_B = 1024        # quant blocks per grid step -> (1024, 256) f32 tiles
@@ -55,42 +57,34 @@ def _kernel(g_ref, s_ref, seeds_ref, q_ref, scale_ref, s_out_ref, *,
     # --- shift update against the dequantized wire value -----------------
     vhat = q * scale[:, None]
     q_ref[...] = q.astype(jnp.int8)
-    scale_ref[...] = scale
+    scale_ref[...] = scale[None, :]
     s_out_ref[...] = s + gamma * vhat
 
 
 def dsc_quantize(g, s, seed_mask, seed_round, *, p: float, gamma: float,
-                 block_b: int = BLOCK_B, interpret: bool = False):
+                 block_b: int = BLOCK_B, interpret: bool | None = None):
     """g: (n,) float; s: (n,) float32; seeds: uint32 scalars.  Ragged n is
-    zero-padded internally to a 256 multiple (zero diff -> zero v -> the
-    padded tail never perturbs scales or shift state).
+    zero-padded internally to whole row blocks (zero diff -> zero v ->
+    the padded tail never perturbs scales or shift state).
 
-    Returns (q int8 (padded_n,), scales f32 (padded_n/256,), s' f32 (n,)).
-    q/scales keep the padded wire layout (what `wire_payload_bytes`
-    accounts for); s' is sliced back to n."""
+    Returns (q int8 (padded_n,), scales f32 (padded_n/256,), s' f32 (n,))
+    with padded_n the next 256 multiple of n.  q/scales keep that wire
+    layout (what `wire_payload_bytes` accounts for); s' is sliced back to
+    n."""
     n = g.shape[0]
-    pad = (-n) % QBLOCK
-    if pad:
-        g = jnp.pad(g, (0, pad))
-        s = jnp.pad(s, (0, pad))
-    nb = (n + pad) // QBLOCK
-    block_b = largest_divisor(nb, min(block_b, nb))
-    g2 = g.reshape(nb, QBLOCK)
-    s2 = s.reshape(nb, QBLOCK)
+    nb = -(-n // QBLOCK)
+    block_b, rows = row_blocks(nb, block_b)
     seeds = jnp.stack([jnp.asarray(seed_mask, jnp.uint32).reshape(()),
                        jnp.asarray(seed_round, jnp.uint32).reshape(())])
+    tile = pl.BlockSpec((block_b, QBLOCK), lambda i: (i, 0))
     q, scale, s_new = pl.pallas_call(
         functools.partial(_kernel, p=p, gamma=gamma, qblock=QBLOCK),
-        grid=(nb // block_b,),
-        in_specs=[pl.BlockSpec((block_b, QBLOCK), lambda i: (i, 0)),
-                  pl.BlockSpec((block_b, QBLOCK), lambda i: (i, 0)),
-                  pl.BlockSpec((2,), lambda i: (0,))],
-        out_specs=(pl.BlockSpec((block_b, QBLOCK), lambda i: (i, 0)),
-                   pl.BlockSpec((block_b,), lambda i: (i,)),
-                   pl.BlockSpec((block_b, QBLOCK), lambda i: (i, 0))),
-        out_shape=(jax.ShapeDtypeStruct((nb, QBLOCK), jnp.int8),
-                   jax.ShapeDtypeStruct((nb,), jnp.float32),
-                   jax.ShapeDtypeStruct((nb, QBLOCK), jnp.float32)),
-        interpret=interpret,
-    )(g2, s2, seeds)
-    return q.reshape(-1), scale, s_new.reshape(-1)[:n]
+        grid=(rows // block_b,),
+        in_specs=[tile, tile, pl.BlockSpec((2,), lambda i: (0,))],
+        out_specs=(tile, pl.BlockSpec((1, block_b), lambda i: (0, i)), tile),
+        out_shape=(jax.ShapeDtypeStruct((rows, QBLOCK), jnp.int8),
+                   jax.ShapeDtypeStruct((1, rows), jnp.float32),
+                   jax.ShapeDtypeStruct((rows, QBLOCK), jnp.float32)),
+        interpret=interpret_mode(interpret),
+    )(pad_rows(g, rows, QBLOCK), pad_rows(s, rows, QBLOCK), seeds)
+    return q[:nb].reshape(-1), scale[0, :nb], s_new.reshape(-1)[:n]

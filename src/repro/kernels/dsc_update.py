@@ -10,8 +10,8 @@ write v, write s') plus a mask read; the fusion does exactly 2 reads +
 the fusion is the roofline optimum.
 
 Tiling: the flat vector is viewed as (rows, 1024) with 1024 = 8*128
-lanes (f32 VMEM tile is (8, 128)); each grid step processes a
-(BLOCK_ROWS, 1024) tile.
+lanes (f32 VMEM tile is (8, 128)), padded to whole row blocks; each
+grid step processes a (BLOCK_ROWS, 1024) tile.
 """
 from __future__ import annotations
 
@@ -21,7 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import largest_divisor, uniform_from_index
+from repro.kernels.common import (interpret_mode, pad_rows, row_blocks,
+                                  uniform_from_index)
 
 LANES = 1024          # 8 * 128
 BLOCK_ROWS = 256      # (256, 1024) f32 tile = 1 MiB in / 2 MiB out of VMEM
@@ -43,38 +44,25 @@ def _kernel(g_ref, s_ref, seed_ref, v_ref, s_out_ref, *, p, gamma, lanes):
 
 
 def dsc_update(g, s, seed, *, p: float, gamma: float,
-               block_rows: int = BLOCK_ROWS, interpret: bool = False):
+               block_rows: int = BLOCK_ROWS, interpret: bool | None = None):
     """g: (n,) any float dtype; s: (n,) float32; seed: uint32 scalar.
-    Ragged n is zero-padded internally to a 1024 multiple: the padded
-    tail has g = s = 0, so v = 0 and s' = 0 there regardless of the mask
-    draw, and the first n coordinates match the unpadded oracle exactly
-    (the RNG is indexed by the global flat position, which padding does
-    not displace).  Returns (v, s'), both length n."""
+    Ragged n is zero-padded internally to whole (block_rows, 1024) tiles:
+    the padded tail has g = s = 0, so v = 0 and s' = 0 there regardless
+    of the mask draw, and the first n coordinates match the unpadded
+    oracle exactly (the RNG is indexed by the global flat position, which
+    padding does not displace).  Returns (v, s'), both length n."""
     n = g.shape[0]
-    pad = (-n) % LANES
-    if pad:
-        g = jnp.pad(g, (0, pad))
-        s = jnp.pad(s, (0, pad))
-    rows = (n + pad) // LANES
-    block_rows = largest_divisor(rows, min(block_rows, rows))
-    grid = (rows // block_rows,)
-    g2 = g.reshape(rows, LANES)
-    s2 = s.reshape(rows, LANES)
+    block_rows, rows = row_blocks(-(-n // LANES), block_rows)
     seed_arr = jnp.asarray([seed], jnp.uint32) if jnp.ndim(seed) == 0 \
         else seed.astype(jnp.uint32)
-    out_shapes = (jax.ShapeDtypeStruct((rows, LANES), g.dtype),
-                  jax.ShapeDtypeStruct((rows, LANES), jnp.float32))
+    tile = pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))
     v, s_new = pl.pallas_call(
         functools.partial(_kernel, p=p, gamma=gamma, lanes=LANES),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
-            pl.BlockSpec((1,), lambda i: (0,)),
-        ],
-        out_specs=(pl.BlockSpec((block_rows, LANES), lambda i: (i, 0)),
-                   pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))),
-        out_shape=out_shapes,
-        interpret=interpret,
-    )(g2, s2, seed_arr)
+        grid=(rows // block_rows,),
+        in_specs=[tile, tile, pl.BlockSpec((1,), lambda i: (0,))],
+        out_specs=(tile, tile),
+        out_shape=(jax.ShapeDtypeStruct((rows, LANES), g.dtype),
+                   jax.ShapeDtypeStruct((rows, LANES), jnp.float32)),
+        interpret=interpret_mode(interpret),
+    )(pad_rows(g, rows, LANES), pad_rows(s, rows, LANES), seed_arr)
     return v.reshape(-1)[:n], s_new.reshape(-1)[:n]

@@ -27,9 +27,16 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.common import interpret_mode
+
 BLOCK_Q = 128
 BLOCK_K = 128
 NEG_INF = -1e30
+# Of the 16 MiB of scoped VMEM a v5e kernel gets by default; the rest
+# holds the per-block tiles and f32 score intermediates.  Rows pad to
+# 128 lanes, so at bf16 and d <= 128 this admits S <= 8192, and the
+# chip's compiler refuses the dq pass at S = 16384 (16.19 MiB).
+WHOLE_SEQ_VMEM = 12 * 1024 * 1024
 
 
 def _mask(s, qpos0, kpos0, block_q, block_k, causal, window):
@@ -84,7 +91,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_q, block_k,
     acc, m, l = jax.lax.fori_loop(lo, hi, body, (acc0, m0, l0))
     l_safe = jnp.maximum(l, 1e-30)
     o_ref[0] = (acc / l_safe[:, None]).astype(o_ref.dtype)
-    lse_ref[0] = m + jnp.log(l_safe)
+    lse_ref[0] = (m + jnp.log(l_safe))[None, :]
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
@@ -92,8 +99,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
     qi = pl.program_id(1)
     q = q_ref[0].astype(jnp.float32) * sm_scale
     do = do_ref[0].astype(jnp.float32)
-    lse = lse_ref[0]                                   # (bq,)
-    delta = delta_ref[0]
+    lse = lse_ref[0, 0]                                # (bq,)
+    delta = delta_ref[0, 0]
     q_offset = qi * block_q
 
     def body(kb, dq):
@@ -134,8 +141,9 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         q = q_ref[0, pl.ds(qb * block_q, block_q), :].astype(jnp.float32) \
             * sm_scale
         do = do_ref[0, pl.ds(qb * block_q, block_q), :].astype(jnp.float32)
-        lse = lse_ref[0, pl.ds(qb * block_q, block_q)]
-        delta = delta_ref[0, pl.ds(qb * block_q, block_q)]
+        q0 = pl.multiple_of(qb * block_q, block_q)
+        lse = lse_ref[0, 0, pl.ds(q0, block_q)]
+        delta = delta_ref[0, 0, pl.ds(q0, block_q)]
         s = q @ k.T                                    # (bq, bk)
         s = _mask(s, qb * block_q, k_offset, block_q, block_k,
                   causal, window)
@@ -201,9 +209,9 @@ def _flash_fwd_call(q, k, v, causal, window, block_q, block_k, interpret):
             pl.BlockSpec((1, S, d), lambda b, i, _m=kv_map: (_m(b), 0, 0)),
         ],
         out_specs=(pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-                   pl.BlockSpec((1, block_q), lambda b, i: (b, i))),
+                   pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i))),
         out_shape=(jax.ShapeDtypeStruct((B * H, S, d), q.dtype),
-                   jax.ShapeDtypeStruct((B * H, S), jnp.float32)),
+                   jax.ShapeDtypeStruct((B * H, 1, S), jnp.float32)),
         interpret=interpret,
     )(qf, kf, vf)
     return out.reshape(B, H, S, d), lse
@@ -219,7 +227,7 @@ def _flash_bwd_call(q, k, v, o, lse, do, causal, window, block_q, block_k,
     dof = do.reshape(B * H, S, d)
     # delta_i = sum_d do_i * o_i — one cheap fused elementwise reduce
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
-                    axis=-1).reshape(B * H, S)
+                    axis=-1).reshape(B * H, 1, S)
     kv_map = functools.partial(_kv_index, H=H, KV=KV)
     kw = dict(block_q=block_q, block_k=block_k, sm_scale=sm_scale,
               causal=causal, window=window, seq_len=S)
@@ -231,8 +239,8 @@ def _flash_bwd_call(q, k, v, o, lse, do, causal, window, block_q, block_k,
             pl.BlockSpec((1, S, d), lambda b, i, _m=kv_map: (_m(b), 0, 0)),
             pl.BlockSpec((1, S, d), lambda b, i, _m=kv_map: (_m(b), 0, 0)),
             pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q), lambda b, i: (b, i)),
-            pl.BlockSpec((1, block_q), lambda b, i: (b, i)),
+            pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i)),
+            pl.BlockSpec((1, 1, block_q), lambda b, i: (b, 0, i)),
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((B * H, S, d), q.dtype),
@@ -251,8 +259,8 @@ def _flash_bwd_call(q, k, v, o, lse, do, causal, window, block_q, block_k,
             pl.BlockSpec((1, block_k, d),
                          lambda b, j, _m=kv_map: (_m(b), j, 0)),
             pl.BlockSpec((1, S, d), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, S), lambda b, j: (b, 0)),
-            pl.BlockSpec((1, S), lambda b, j: (b, 0)),
+            pl.BlockSpec((1, 1, S), lambda b, j: (b, 0, 0)),
+            pl.BlockSpec((1, 1, S), lambda b, j: (b, 0, 0)),
         ],
         out_specs=(pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0)),
                    pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0))),
@@ -287,20 +295,23 @@ _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 def flash_attention(q, k, v, *, causal: bool = True, window=None,
                     block_q: int = BLOCK_Q, block_k: int = BLOCK_K,
-                    interpret: bool = False):
+                    interpret: bool | None = None):
     """q: (B, H, S, d); k, v: (B, KV, S, d) with H % KV == 0 (GQA).
     Returns (B, H, S, d).  S % block == 0 after blocks clamp to S.
     Differentiable (custom VJP, blocked recompute backward)."""
     if window is not None:
         assert causal, "sliding window implies causal masking"
     cfg = (bool(causal), None if window is None else int(window),
-           int(block_q), int(block_k), bool(interpret))
+           int(block_q), int(block_k), interpret_mode(interpret))
     return _flash(q, k, v, cfg)
 
 
-def supports(S: int, d: int, block_q: int = BLOCK_Q,
+def supports(S: int, d: int, itemsize: int = 4, block_q: int = BLOCK_Q,
              block_k: int = BLOCK_K) -> bool:
     """Shape gate for the training integration: the kernels need the
-    (possibly clamped) blocks to tile S exactly."""
+    (possibly clamped) blocks to tile S exactly, and the two
+    whole-sequence operands each pass holds in VMEM (K/V forward and for
+    dq, q/dO for dk/dv), double-buffered, to fit ``WHOLE_SEQ_VMEM``."""
     bq, bk = min(block_q, S), min(block_k, S)
-    return S % bq == 0 and S % bk == 0
+    resident = 2 * 2 * S * max(d, 128) * itemsize
+    return S % bq == 0 and S % bk == 0 and resident <= WHOLE_SEQ_VMEM

@@ -39,6 +39,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.common import interpret_mode
+
 NEG_INF = -1e30
 
 
@@ -91,7 +93,7 @@ def _kernel(tbl_ref, ctx_ref, q_ref, k_ref, v_ref, o_ref,
 def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                     block_tables: jax.Array, context_lens: jax.Array, *,
                     window: Optional[int] = None,
-                    interpret: bool = False) -> jax.Array:
+                    interpret: Optional[bool] = None) -> jax.Array:
     """One decode step of paged GQA attention.
 
     q:            (B, H, hd)  — the new tokens' query heads
@@ -139,7 +141,7 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
     out = pl.pallas_call(
         kernel, grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B * KV, G, hd), q.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(block_tables.astype(jnp.int32), context_lens.astype(jnp.int32),
       qf, kf, vf)
     return out.reshape(B, H, hd)
@@ -148,7 +150,7 @@ def paged_attention(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
 def paged_attention_ref(q: jax.Array, k_pool: jax.Array, v_pool: jax.Array,
                         block_tables: jax.Array, context_lens: jax.Array, *,
                         window: Optional[int] = None,
-                        interpret: bool = False) -> jax.Array:
+                        interpret: Optional[bool] = None) -> jax.Array:
     """jnp reference / fallback: gather each request's pages from the
     pools, then masked softmax attention.  Same signature and semantics
     as :func:`paged_attention` (``interpret`` accepted and ignored)."""

@@ -7,6 +7,9 @@ paper's Definition 3.1 analysis as an omega-compressor.
 
 Tiling: flat vector viewed as (n_blocks, 256); a grid step handles
 (BLOCK_B, 256) = up to 1 MiB of f32 in VMEM, emitting int8 + scales.
+The block count pads up to whole row blocks (``common.row_blocks``), and
+the scales travel as a lane-dense (1, n_blocks) row: a 1-D or (n, 1)
+scale block cannot be tiled by the chip.
 """
 from __future__ import annotations
 
@@ -16,7 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import largest_divisor, uniform_from_index
+from repro.kernels.common import (interpret_mode, pad_rows, row_blocks,
+                                  uniform_from_index)
 
 QBLOCK = 256          # coords per scale
 BLOCK_B = 1024        # quant blocks per grid step
@@ -46,54 +50,51 @@ def _quant_kernel(x_ref, seed_ref, q_ref, scale_ref, *, qblock):
     u = uniform_from_index(idx, seed_ref[0])
     q = low + (u < frac).astype(jnp.float32)
     q_ref[...] = jnp.clip(q, -127, 127).astype(jnp.int8)
-    scale_ref[...] = scale
+    scale_ref[...] = scale[None, :]
 
 
 def _dequant_kernel(q_ref, scale_ref, x_ref):
-    x_ref[...] = q_ref[...].astype(jnp.float32) * scale_ref[...][:, None]
+    x_ref[...] = q_ref[...].astype(jnp.float32) * scale_ref[0][:, None]
 
 
-def quantize(x, seed, *, block_b: int = BLOCK_B, interpret: bool = False):
+def quantize(x, seed, *, block_b: int = BLOCK_B,
+             interpret: bool | None = None):
     """x: (n,) float.  Ragged n zero-pads to the next 256 multiple (the
     wire layout `wire_payload_bytes` accounts for; zeros quantize to 0
     deterministically and never move a block scale).  Returns
     (q int8 (padded_n,), scales (padded_n/256,)), matching the oracle's
     padded layout."""
-    n = x.shape[0]
-    pad = (-n) % QBLOCK
-    if pad:
-        x = jnp.pad(x, (0, pad))
-    nb = (n + pad) // QBLOCK
-    block_b = largest_divisor(nb, min(block_b, nb))
-    x2 = x.reshape(nb, QBLOCK)
+    nb = -(-x.shape[0] // QBLOCK)
+    block_b, rows = row_blocks(nb, block_b)
     seed_arr = jnp.asarray([seed], jnp.uint32) if jnp.ndim(seed) == 0 \
         else seed.astype(jnp.uint32)
     q, scale = pl.pallas_call(
         functools.partial(_quant_kernel, qblock=QBLOCK),
-        grid=(nb // block_b,),
+        grid=(rows // block_b,),
         in_specs=[pl.BlockSpec((block_b, QBLOCK), lambda i: (i, 0)),
                   pl.BlockSpec((1,), lambda i: (0,))],
         out_specs=(pl.BlockSpec((block_b, QBLOCK), lambda i: (i, 0)),
-                   pl.BlockSpec((block_b,), lambda i: (i,))),
-        out_shape=(jax.ShapeDtypeStruct((nb, QBLOCK), jnp.int8),
-                   jax.ShapeDtypeStruct((nb,), jnp.float32)),
-        interpret=interpret,
-    )(x2, seed_arr)
-    return q.reshape(-1), scale
+                   pl.BlockSpec((1, block_b), lambda i: (0, i))),
+        out_shape=(jax.ShapeDtypeStruct((rows, QBLOCK), jnp.int8),
+                   jax.ShapeDtypeStruct((1, rows), jnp.float32)),
+        interpret=interpret_mode(interpret),
+    )(pad_rows(x, rows, QBLOCK), seed_arr)
+    return q[:nb].reshape(-1), scale[0, :nb]
 
 
-def dequantize(q, scale, *, block_b: int = BLOCK_B, interpret: bool = False):
+def dequantize(q, scale, *, block_b: int = BLOCK_B,
+               interpret: bool | None = None):
     n = q.shape[0]
     assert n % QBLOCK == 0, n
     nb = n // QBLOCK
-    block_b = largest_divisor(nb, min(block_b, nb))
+    block_b, rows = row_blocks(nb, block_b)
     out = pl.pallas_call(
         _dequant_kernel,
-        grid=(nb // block_b,),
+        grid=(rows // block_b,),
         in_specs=[pl.BlockSpec((block_b, QBLOCK), lambda i: (i, 0)),
-                  pl.BlockSpec((block_b,), lambda i: (i,))],
+                  pl.BlockSpec((1, block_b), lambda i: (0, i))],
         out_specs=pl.BlockSpec((block_b, QBLOCK), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((nb, QBLOCK), jnp.float32),
-        interpret=interpret,
-    )(q.reshape(nb, QBLOCK), scale)
-    return out.reshape(n)
+        out_shape=jax.ShapeDtypeStruct((rows, QBLOCK), jnp.float32),
+        interpret=interpret_mode(interpret),
+    )(pad_rows(q, rows, QBLOCK), pad_rows(scale, 1, rows))
+    return out[:nb].reshape(n)

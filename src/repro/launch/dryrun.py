@@ -9,10 +9,13 @@ Usage:
     python -m repro.launch.dryrun --arch qwen3-32b --shape train_4k \
         [--multi-pod] [--dsc] [--out experiments/dryrun]
 """
-# The dry-run (and ONLY the dry-run) needs 512 placeholder devices — set
-# before ANY other import; jax locks the device count on first init.
+# The dry-run (and ONLY the dry-run) needs 512 placeholder CPU devices —
+# set before ANY other import; jax locks the device count on first init.
+# It is a CPU lowering tool: pinning the platform keeps it (and every
+# child that launch/sweep.py starts) off an attached TPU.
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 import argparse          # noqa: E402
 import json              # noqa: E402
@@ -73,8 +76,6 @@ def run_one(arch: str, shape_name: str, multi_pod: bool,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):          # older jax: one dict per partition
-        cost = cost[0] if cost else {}
     hlo = compiled.as_text()
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
     model_size = sizes.get("model", 1)

@@ -6,6 +6,17 @@ state.  Target hardware: TPU v5e, 256 chips/pod (16x16), optionally 2 pods.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
+    """``jax.make_mesh`` with every axis ``Auto``: the runtime places
+    arrays with GSPMD shardings and enters manual regions through
+    ``shard_map``; explicit-sharding types (the default of
+    ``jax.make_mesh``) would make every jnp op on a sharded array outside
+    those regions, e.g. the serving engine's matmuls on trained params,
+    demand an output sharding."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False, pipe: int = 1):
@@ -19,12 +30,12 @@ def make_production_mesh(*, multi_pod: bool = False, pipe: int = 1):
     if pipe == 1:
         shape = (2, 16, 16) if multi_pod else (16, 16)
         axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-        return jax.make_mesh(shape, axes)
+        return _auto_mesh(shape, axes)
     shape = ((2, 16 // pipe, pipe, 16) if multi_pod
              else (16 // pipe, pipe, 16))
     axes = (("pod", "data", "pipe", "model") if multi_pod
             else ("data", "pipe", "model"))
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(data: int | None = None, model: int = 1,
@@ -54,8 +65,8 @@ def make_host_mesh(data: int | None = None, model: int = 1,
             f"{data * inner} devices but {n} are available; leave "
             f"data=None to infer data = n // (model*pipe) = {n // inner}")
     if pipe == 1:
-        return jax.make_mesh((data, model), ("data", "model"))
-    return jax.make_mesh((data, pipe, model), ("data", "pipe", "model"))
+        return _auto_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, pipe, model), ("data", "pipe", "model"))
 
 
 # hardware constants for the roofline model (TPU v5e)

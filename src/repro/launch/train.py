@@ -1,9 +1,9 @@
 """Distributed train step: FSA expressed as explicit TPU collectives.
 
 The step is ONE fully-manual ``shard_map`` over ALL mesh axes — the
-partial-auto mode (manual client axes + GSPMD model axis) trips a
-jax-0.4.37 SPMD-partitioner check (``IsManualSubgroup``) on the 512-device
-configs, so the lowering keeps nothing automatic:
+partial-auto mode (manual client axes + GSPMD model axis) tripped an
+SPMD-partitioner check (``IsManualSubgroup``) on the 512-device configs,
+so the lowering keeps nothing automatic:
 
   1. *FSA broadcast* — stored parameters are sharded over the client axes
      (each position = one aggregator's disjoint segment, Sec. 3.2.1); the
@@ -50,7 +50,8 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Optional
+import time
+from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
@@ -188,25 +189,21 @@ def _client_size(mesh: Mesh) -> int:
     return sh.client_count(mesh)
 
 
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+def _wire_rows(x: jax.Array, n_client: int) -> jax.Array:
+    """Each client's wire row as a lane-dense (rows, 128) tile stack.  A
+    flat row per client pads to the chip's sublane tiling, and at
+    embedding size its all_to_all and the cross-client reduce compile
+    for minutes."""
+    return x.reshape(n_client, -1, 128)
 
 
-def _shard_map(f, mesh: Mesh, in_specs, out_specs):
-    """Fully-manual shard_map (every mesh axis manual), compatible with
-    both the jax>=0.5 top-level API and the 0.4.x experimental one."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
-def _quant_block_b(n_blocks: int) -> int:
-    from repro.kernels import quantize as q_kernel
-    from repro.kernels.common import largest_divisor
-    return largest_divisor(n_blocks, q_kernel.BLOCK_B)
+def _reduce_rows(rx: jax.Array, m: int, weights=None) -> jax.Array:
+    """Aggregator-side reduce of the received (n_client, rows, 128) tiles
+    to the segment's first ``m`` coordinates: the mean over clients, or
+    the ``weights``-weighted sum."""
+    my = (rx.mean(0) if weights is None
+          else jnp.einsum("k,krl->rl", weights, rx))
+    return my.reshape(-1)[:m]
 
 
 def _int8_wire_exchange(v: jax.Array, dim: int, seed: jax.Array,
@@ -231,15 +228,12 @@ def _int8_wire_exchange(v: jax.Array, dim: int, seed: jax.Array,
     m, mp = lay.shard_elems, lay.padded_elems        # geometry on the wire
     rows = sh.split_shards(v.astype(jnp.float32), dim, n_client)
     rows = jnp.pad(rows, ((0, 0), (0, mp - m)))
-    block_b = _quant_block_b(n_client * lay.n_blocks)
-    q, scale = q_kernel.quantize(rows.reshape(-1), seed, block_b=block_b,
-                                 interpret=_interpret())
-    q = q.reshape(n_client, mp)
+    q, scale = q_kernel.quantize(rows.reshape(-1), seed)
+    q = _wire_rows(q, n_client)
     scale = scale.reshape(n_client, lay.n_blocks)
 
     def deq(qq, ss):
-        out = q_kernel.dequantize(qq.reshape(-1), ss.reshape(-1),
-                                  block_b=block_b, interpret=_interpret())
+        out = q_kernel.dequantize(qq.reshape(-1), ss.reshape(-1))
         return out.reshape(n_client, mp)[:, :m]
 
     v_hat = None
@@ -248,17 +242,19 @@ def _int8_wire_exchange(v: jax.Array, dim: int, seed: jax.Array,
     # --- the wire: int8 blocks + f32 scales cross the client axes -------
     q_rx = jax.lax.all_to_all(q, caxis, 0, 0, tiled=True)
     s_rx = jax.lax.all_to_all(scale, caxis, 0, 0, tiled=True)
-    rx_rows = deq(q_rx, s_rx)                         # (n_client, m) views
+    rx = _wire_rows(q_kernel.dequantize(q_rx.reshape(-1), s_rx.reshape(-1)),
+                    n_client)
+    rx_rows = rx.reshape(n_client, mp)[:, :m]         # (n_client, m) views
     if rx_w is not None:
         # failure-injected receive: rows weighted by live links,
         # renormalized by the live count (already folded into rx_w)
-        my = jnp.einsum("k,km->m", rx_w, rx_rows)
+        my = _reduce_rows(rx, m, rx_w)
     elif omega is None:
-        my = rx_rows.mean(0)                          # aggregator-side sum
+        my = _reduce_rows(rx, m)                      # aggregator-side sum
     else:
         # staleness/dropout-weighted arrivals (async buffer): each row is
         # one client's segment, discounted by its arrival weight
-        my = jnp.einsum("k,km->m", omega, rx_rows) / n_client
+        my = _reduce_rows(rx, m, omega) / n_client
     shard_shape = list(v.shape)
     shard_shape[dim] //= n_client
     return my.reshape(shard_shape), v_hat, rx_rows
@@ -289,25 +285,22 @@ def _fused_wire_exchange(g: jax.Array, s: jax.Array, dim: int,
                      ((0, 0), (0, mp - m)))
     s_rows = jnp.pad(sh.split_shards(s.astype(jnp.float32), dim, n_client),
                      ((0, 0), (0, mp - m)))
-    block_b = _quant_block_b(n_client * lay.n_blocks)
     q, scale, s_new_flat = dq_kernel.dsc_quantize(
         g_rows.reshape(-1), s_rows.reshape(-1), seed_mask, seed_round,
-        p=p, gamma=gamma, block_b=block_b, interpret=_interpret())
-    q = q.reshape(n_client, mp)
+        p=p, gamma=gamma)
+    q = _wire_rows(q, n_client)
     scale = scale.reshape(n_client, lay.n_blocks)
     s_new = sh.merge_shards(s_new_flat.reshape(n_client, mp)[:, :m],
                             dim, g.shape, n_client).astype(s.dtype)
     # --- the wire: int8 blocks + f32 scales cross the client axes -------
     q_rx = jax.lax.all_to_all(q, caxis, 0, 0, tiled=True)
     s_rx = jax.lax.all_to_all(scale, caxis, 0, 0, tiled=True)
-    rx = q_kernel.dequantize(q_rx.reshape(-1), s_rx.reshape(-1),
-                             block_b=block_b, interpret=_interpret())
+    rx = _wire_rows(q_kernel.dequantize(q_rx.reshape(-1), s_rx.reshape(-1)),
+                    n_client)
     rx_rows = rx.reshape(n_client, mp)[:, :m]
     shard_shape = list(g.shape)
     shard_shape[dim] //= n_client
-    my = (rx_rows.mean(0) if rx_w is None
-          else jnp.einsum("k,km->m", rx_w, rx_rows))
-    return my.reshape(shard_shape), s_new, rx_rows
+    return _reduce_rows(rx, m, rx_w).reshape(shard_shape), s_new, rx_rows
 
 
 def make_train_step(cfg: ModelConfig, mesh: Mesh, opt: Optimizer,
@@ -687,7 +680,10 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, opt: Optimizer,
         grads = jax.tree.map(lambda g, p: g.astype(p.dtype), grads,
                              params_shard)
         delta, opt_state_new = opt.update(grads, opt_state, params_shard)
-        params_new = jax.tree.map(jnp.add, params_shard, delta)
+        # the update may be wider than the params (adam's f32 bias
+        # correction): params keep their dtype across steps
+        params_new = jax.tree.map(lambda p, d: (p + d).astype(p.dtype),
+                                  params_shard, delta)
         if settings.async_buffer and async_cfg.buffer_cadence > 1:
             # the server consumes the buffer only on cadence rounds:
             # params and optimizer state hold still in between
@@ -782,7 +778,7 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, opt: Optimizer,
                          {"loss": P(), "grad_norm": P()})
             if view_specs is not None:
                 out_specs = out_specs + (view_specs,)
-            fn = _shard_map(
+            fn = sh.shard_map(
                 functools.partial(fsa_body, model_split=model_split), mesh,
                 in_specs=in_specs, out_specs=out_specs)
             return fn(jnp.arange(n_client, dtype=jnp.int32),
@@ -893,7 +889,53 @@ def lower_train_step(cfg: ModelConfig, mesh: Mesh,
         return jitted.lower(params, opt_state, dsc_ref, batch, key)
 
 
-def main():  # pragma: no cover - thin CLI over the factories
+@dataclasses.dataclass
+class TrainRun:
+    """What :func:`train` returns: the final stored params, optimizer and
+    DSC state, the loss of every step, and the compiled step (its text
+    shows which kernels the compiler kept).  Times are host wall clock
+    in seconds."""
+    params: Any
+    opt_state: Any
+    dsc_ref: Any
+    losses: list[float]
+    compiled: Any
+    compile_s: float
+    step_s: list[float]
+
+
+def train(cfg: ModelConfig, mesh: Mesh, opt: Optimizer,
+          settings: TrainSettings, batch, steps: int,
+          key: Optional[jax.Array] = None, log=None) -> TrainRun:
+    """Distributed FSA training on ``mesh``: parameters initialized from
+    ``key`` and placed in the store layout, the step compiled once (with
+    the state donated), then ``steps`` steps on the one ``batch``.
+    ``log``, if given, receives a line per step."""
+    key = jax.random.PRNGKey(0) if key is None else key
+    step, shardings = make_train_step(cfg, mesh, opt, settings)
+    with mesh:
+        params = jax.device_put(tr.init_params(key, cfg), shardings["store"])
+        opt_state = opt.init(params)
+        dsc_ref = init_dsc_state(cfg, mesh, settings)
+        t0 = time.perf_counter()
+        compiled = jax.jit(step, donate_argnums=(0, 1, 2)).lower(
+            params, opt_state, dsc_ref, batch, key).compile()
+        compile_s = time.perf_counter() - t0
+        losses, step_s = [], []
+        for i in range(steps):
+            t0 = time.perf_counter()
+            params, opt_state, dsc_ref, m = compiled(
+                params, opt_state, dsc_ref, batch, jax.random.PRNGKey(i))
+            losses.append(float(m["loss"]))
+            step_s.append(time.perf_counter() - t0)
+            if log is not None:
+                log(f"step {i:3d} loss={losses[-1]:.4f} "
+                    f"({step_s[-1]:.2f}s)")
+    return TrainRun(params, opt_state, dsc_ref, losses, compiled,
+                    compile_s, step_s)
+
+
+def main():  # pragma: no cover - thin CLI over train()
     """CLI: distributed FSA training on the host devices.
 
         XLA_FLAGS=--xla_force_host_platform_device_count=8 \
@@ -901,9 +943,9 @@ def main():  # pragma: no cover - thin CLI over the factories
             --smoke --steps 20
     """
     import argparse
-    import time
     from repro.configs import get_config
     from repro.data import lm_token_batches
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.launch.mesh import make_host_mesh
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-0.5b")
@@ -926,35 +968,24 @@ def main():  # pragma: no cover - thin CLI over the factories
                          "directory (the ServeEngine.from_checkpoint "
                          "handoff format)")
     args = ap.parse_args()
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.smoke:
         cfg = cfg.smoke()
     mesh = make_host_mesh(data=args.data_axis, model=args.model_axis,
                           pipe=args.pp)
-    opt = adam(args.lr)
     settings = TrainSettings(use_dsc=args.dsc, grad_dtype="float32",
                              int8_wire=args.int8_wire,
                              microbatches=args.microbatches)
-    step, shardings = make_train_step(cfg, mesh, opt, settings)
     key = jax.random.PRNGKey(0)
-    with mesh:
-        params = jax.device_put(tr.init_params(key, cfg),
-                                shardings["store"])
-        opt_state = opt.init(params)
-        dsc_ref = init_dsc_state(cfg, mesh, settings)
-        toks = lm_token_batches(key, 1, args.batch, args.seq, cfg.vocab)[0]
-        batch = {"tokens": toks}
-        jstep = jax.jit(step)
-        t0 = time.time()
-        for i in range(args.steps):
-            params, opt_state, dsc_ref, m = jstep(
-                params, opt_state, dsc_ref, batch, jax.random.PRNGKey(i))
-            print(f"step {i:3d} loss={float(m['loss']):.4f} "
-                  f"({time.time()-t0:.1f}s)", flush=True)
-        if args.save:
-            from repro.checkpoint import msgpack_ckpt as ck
-            ck.save_sharded(args.save, params)
-            print(f"saved sharded checkpoint -> {args.save}", flush=True)
+    toks = lm_token_batches(key, 1, args.batch, args.seq, cfg.vocab)[0]
+    run = train(cfg, mesh, adam(args.lr), settings, {"tokens": toks},
+                args.steps, key, log=lambda line: print(line, flush=True))
+    print(f"compiled in {run.compile_s:.1f}s", flush=True)
+    if args.save:
+        from repro.checkpoint import msgpack_ckpt as ck
+        ck.save_sharded(args.save, run.params)
+        print(f"saved sharded checkpoint -> {args.save}", flush=True)
 
 
 if __name__ == "__main__":

@@ -106,7 +106,7 @@ def tp_psum(x, axis):
     is consumed on every shard (e.g. the channel-sharded RMS-norm
     variance): every position's cotangent contributes to every
     position's operand, so the backward must itself sum over the axis.
-    (Under the manual region's check_rep=False a plain ``jax.lax.psum``
+    (Under the manual region's check_vma=False a plain ``jax.lax.psum``
     happens to transpose to psum as well, but spelling the pair out
     keeps the semantics independent of that implementation detail —
     see ``tp_pull`` for the identity-backward exit.)"""

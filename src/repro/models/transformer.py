@@ -23,10 +23,6 @@ from repro.models import ssm as ssm_lib
 from repro.models.config import ModelConfig
 
 
-def _flash_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 def _ring(cfg: ModelConfig, tp) -> int:
     """Static model-axis size when ring-overlapped collectives are on
     (0 selects the monolithic psum conjugates)."""
@@ -220,16 +216,20 @@ def _attn(cfg: ModelConfig, lp, x, positions, mode, cache, window, tp=None):
         new_cache = {"k": k_cache, "v": v_cache}
     else:
         from repro.kernels import flash_attention as fa
-        if (cfg.flash_attention and mode == "train"
-                and not cfg.attn_batch_shard and window != 0
-                and fa.supports(S, cfg.hd)):
+        from repro.kernels.common import log_fallback
+        use_flash = (cfg.flash_attention and mode == "train"
+                     and not cfg.attn_batch_shard and window != 0)
+        if use_flash and not fa.supports(S, cfg.hd, q.dtype.itemsize):
+            log_fallback("flash_attention", f"S={S} d={cfg.hd} "
+                         f"{q.dtype} is outside the kernel's shape gate")
+            use_flash = False
+        if use_flash:
             # blocked online-softmax kernel, custom-VJP backward: no S x S
             # score materialization in either pass.  Head counts here are
             # already TP-local; a seq plan entered above, so S is full.
             out = fa.flash_attention(
                 q.swapaxes(1, 2), k.swapaxes(1, 2), v.swapaxes(1, 2),
-                causal=True, window=window,
-                interpret=_flash_interpret()).swapaxes(1, 2)
+                causal=True, window=window).swapaxes(1, 2)
         else:
             out = L.causal_attention(
                 q, k, v, window=window, chunk=cfg.attn_chunk,
@@ -750,7 +750,7 @@ def pipeline_loss_fn(params, cfg: ModelConfig, batch, window=None,
     # own layers' load-balance aux — both assemble with the psum-forward
     # / identity-backward conjugate so every stage gets the SAME 1/m
     # cotangent and prices its own contribution exactly once (a plain
-    # psum transposes to psum under the manual region's check_rep=False,
+    # psum transposes to psum under the manual region's check_vma=False,
     # which would scale every gradient by the stage count)
     loss = L.tp_pull(loss_sum, pipe.axis) / m
     if cfg.family == "moe":
@@ -863,11 +863,14 @@ def _attn_paged(cfg: ModelConfig, lp, x, positions, k_pool, v_pool,
         k[:, 0].astype(k_pool.dtype))
     v_pool = v_pool.at[pages, :, offs].set(
         v[:, 0].astype(v_pool.dtype))
-    fn = (pa.paged_attention
-          if use_kernel and pa.supports(n_heads, n_kv, cfg.hd)
-          else pa.paged_attention_ref)
+    fn = pa.paged_attention if use_kernel else pa.paged_attention_ref
+    if use_kernel and not pa.supports(n_heads, n_kv, cfg.hd):
+        from repro.kernels.common import log_fallback
+        log_fallback("paged_attention", f"H={n_heads} KV={n_kv} "
+                     f"hd={cfg.hd} is outside the kernel's shape gate")
+        fn = pa.paged_attention_ref
     out = fn(q[:, 0], k_pool, v_pool, block_tables, ctx_lens + 1,
-             window=window, interpret=_flash_interpret())
+             window=window)
     y = out.reshape(B, 1, n_heads * cfg.hd) @ lp["wo"]
     if tp_attn:
         y = jax.lax.psum(y, tp.axis)        # row-parallel wo partials

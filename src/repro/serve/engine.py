@@ -53,18 +53,6 @@ from repro.serve import cache as pc
 from repro.serve.sampling import SamplingParams, sample
 
 
-def _shard_map(f, mesh, in_specs, out_specs):
-    """Fully-manual shard_map (every mesh axis manual), compatible with
-    both the jax>=0.5 top-level API and the 0.4.x experimental one —
-    the same shim ``launch/train.py`` uses for the train step."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
 @dataclasses.dataclass(frozen=True)
 class ServeSettings:
     """Serving configuration (the ``TrainSettings`` twin for the other
@@ -191,7 +179,7 @@ class ServeEngine:
                     jnp.arange(model, dtype=jnp.int32),
                     NamedSharding(mesh, PartitionSpec("model")))
                 bspec = self._batch_sh.spec
-                self._decode_body = _shard_map(
+                self._decode_body = sh.shard_map(
                     self._manual_decode_fn, mesh,
                     in_specs=(PartitionSpec("model"), pspecs,
                               {"k": pool_spec, "v": pool_spec},
@@ -207,6 +195,10 @@ class ServeEngine:
             # the kernel is fine meshless and inside the manual body; it
             # is only the GSPMD fallback that cannot partition it
             self._use_kernel = mesh is None or self._manual
+            if not self._use_kernel:
+                from repro.kernels.common import log_fallback
+                log_fallback("paged_attention", f"{C} decode slots do not "
+                             f"divide over the mesh's client positions")
         else:
             self._use_kernel = settings.decode_kernel == "pallas"
         self.params = params
@@ -446,7 +438,23 @@ class ServeEngine:
         if not active:
             return finished
 
-        C = s.max_concurrency
+        nxt, self.pools = self._decode(self.params, self.pools,
+                                       *self._decode_inputs(active))
+        nxt = np.asarray(nxt)
+        now = time.monotonic()
+        for r in active:
+            r.generated.append(int(nxt[r.slot]))
+            self._tokens_out += 1
+            if r.first_token_t is None:
+                r.first_token_t = now
+            if self._done(r):
+                finished.append(self._evict(r, self._done(r)))
+        return finished
+
+    def _decode_inputs(self, active: List[Request]) -> tuple:
+        """The per-slot batch of one decode step for the ``active``
+        requests (inactive slots hold the scratch table row)."""
+        C = self.settings.max_concurrency
         toks = np.zeros((C, 1), np.int32)
         ctxs = np.zeros((C,), np.int32)
         keys = np.zeros((C, 2), np.uint32)
@@ -460,25 +468,15 @@ class ServeEngine:
             temps[r.slot] = r.sampling.temperature
             tks[r.slot] = r.sampling.top_k
             tps[r.slot] = r.sampling.top_p
-        nxt, self.pools = self._decode(
-            self.params, self.pools,
-            self._put_batch(jnp.asarray(self.tables)),
-            self._put_batch(jnp.asarray(ctxs)),
-            self._put_batch(jnp.asarray(toks)),
-            self._put_batch(jnp.asarray(keys)),
-            self._put_batch(jnp.asarray(temps)),
-            self._put_batch(jnp.asarray(tks)),
-            self._put_batch(jnp.asarray(tps)))
-        nxt = np.asarray(nxt)
-        now = time.monotonic()
-        for r in active:
-            r.generated.append(int(nxt[r.slot]))
-            self._tokens_out += 1
-            if r.first_token_t is None:
-                r.first_token_t = now
-            if self._done(r):
-                finished.append(self._evict(r, self._done(r)))
-        return finished
+        return tuple(self._put_batch(jnp.asarray(x)) for x in
+                     (self.tables, ctxs, toks, keys, temps, tks, tps))
+
+    def lower_decode(self):
+        """The engine's own decode step, lowered for its params and pools
+        (``jax.stages.Lowered``): its text shows whether the Pallas
+        paged-attention kernel (``tpu_custom_call``) is in the program."""
+        return self._decode.lower(self.params, self.pools,
+                                  *self._decode_inputs([]))
 
     def _done(self, r: Request) -> str:
         if self.settings.eos_id is not None and r.generated and \

@@ -97,3 +97,24 @@ def test_rope_relative():
         return float((xp * yq).sum())
     assert dot_at(3, 1) == pytest.approx(dot_at(12, 10), abs=1e-4)
     assert dot_at(3, 1) != pytest.approx(dot_at(3, 2), abs=1e-3)
+
+
+def test_flash_shape_gate_falls_back_loudly_once(caplog):
+    """A sequence the flash kernel cannot tile takes the chunked path,
+    and says so once, not silently and not on every trace."""
+    import dataclasses
+    import logging
+    from repro.configs import get_config
+    from repro.kernels import flash_attention as fa
+    from repro.kernels.common import log_fallback
+    from repro.models import transformer as tr
+    cfg = dataclasses.replace(get_config("qwen2-0.5b").smoke(), n_layers=1)
+    assert cfg.flash_attention and not fa.supports(200, cfg.hd)
+    params = tr.init_params(KEY, cfg)
+    toks = jax.random.randint(KEY, (1, 200), 0, cfg.vocab)
+    log_fallback.cache_clear()
+    with caplog.at_level(logging.WARNING, logger="repro.kernels"):
+        losses = [tr.loss_fn(params, cfg, {"tokens": toks}) for _ in range(2)]
+    assert np.isfinite(np.asarray(losses)).all()
+    assert sum("flash_attention" in r.getMessage()
+               for r in caplog.records) == 1

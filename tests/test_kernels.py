@@ -158,6 +158,35 @@ def test_dsc_quantize_matches_unfused_chain():
                                rtol=1e-5, atol=1e-6)
 
 
+# ------------------------------------------- several grid steps, ragged tail
+# Row blocks are whole tiles and the row count pads up to whole blocks;
+# the draws are indexed by global position, so a grid of several steps
+# with a padded last block must still draw exactly what the oracle does.
+@pytest.mark.parametrize("kernel", ["dsc_update", "quantize",
+                                    "dsc_quantize"])
+def test_multi_block_grid_matches_ref(kernel):
+    g = jax.random.normal(KEY, (3 * 32 * 1024 + 37,))
+    s = 0.1 * jax.random.normal(jax.random.fold_in(KEY, 1), g.shape)
+    sm, sr = jnp.uint32(5), jnp.uint32(6)
+    if kernel == "dsc_update":
+        got = dsc_update(g, s, sm, p=0.3, gamma=0.5, block_rows=32,
+                         interpret=True)
+        want = ref.dsc_update_ref(g, s, sm, p=0.3, gamma=0.5)
+    elif kernel == "quantize":
+        q, sc = quantize(g, sm, block_b=64, interpret=True)
+        got = (q, sc, dequantize(q, sc, block_b=64, interpret=True))
+        q_ref, sc_ref = ref.quantize_ref(g, sm)
+        want = (q_ref, sc_ref, ref.dequantize_ref(q_ref, sc_ref))
+    else:
+        got = dsc_quantize(g, s, sm, sr, p=0.3, gamma=0.5, block_b=64,
+                           interpret=True)
+        want = ref.dsc_quantize_ref(g, s, sm, sr, p=0.3, gamma=0.5)
+    for a, b in zip(got, want):     # int8 q and scales match exactly
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b, np.float32),
+                                   rtol=1e-5, atol=1e-6)
+
+
 # ------------------------------------------------------- flash attention
 @pytest.mark.parametrize("S,bq,bk", [(128, 128, 128), (256, 128, 64),
                                      (256, 64, 128)])

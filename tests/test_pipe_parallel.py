@@ -128,14 +128,6 @@ _PIPE_PRELUDE = textwrap.dedent("""
     from repro.models import shard_plan as sp
     from repro.models import transformer as tr
 
-    def _shard_map(f, mesh, in_specs, out_specs):
-        if hasattr(jax, "shard_map"):
-            return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                                 out_specs=out_specs, check_vma=False)
-        from jax.experimental.shard_map import shard_map as _sm
-        return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                   check_rep=False)
-
     def run_case(name, tp, pipe, mb, cfg):
         B, S = 8, 32
         toks = jax.random.randint(jax.random.fold_in(
@@ -178,9 +170,9 @@ _PIPE_PRELUDE = textwrap.dedent("""
             grads = sh.pipe_grad_sync(grads, pdims, "pipe")
             return loss, grads
 
-        fn = _shard_map(body, mesh,
-                        in_specs=(pspec, P("pipe"), P("model")),
-                        out_specs=(P(), pspec))
+        fn = sh.shard_map(body, mesh,
+                          in_specs=(pspec, P("pipe"), P("model")),
+                          out_specs=(P(), pspec))
         with mesh:
             loss, grads = jax.jit(fn)(
                 params, jnp.arange(pipe, dtype=jnp.int32),

@@ -222,7 +222,6 @@ _PARITY_PRELUDE = textwrap.dedent("""
     from jax.sharding import Mesh, PartitionSpec as P
     from repro.configs import get_config
     from repro.dist import sharding as sh
-    from repro.launch.train import _shard_map
     from repro.models import transformer as tr
 
     KEY = jax.random.PRNGKey(0)
@@ -254,8 +253,8 @@ _PARITY_PRELUDE = textwrap.dedent("""
             grads = sh.tp_grad_sync(grads, specs, "model")
             return loss, grads
 
-        fn = _shard_map(body, mesh, in_specs=(pspec, P("model")),
-                        out_specs=(P(), pspec))
+        fn = sh.shard_map(body, mesh, in_specs=(pspec, P("model")),
+                          out_specs=(P(), pspec))
         with mesh:
             loss, grads = jax.jit(fn)(params,
                                       jnp.arange(tp, dtype=jnp.int32))
