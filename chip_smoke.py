@@ -90,8 +90,7 @@ def train_phase(cfg, mesh, settings, name: str, batch, steps: int,
     from repro.optim import adam
     run = train(cfg, mesh, adam(lr), settings, batch, steps,
                 log=lambda line: print(f"train/{name} {line}", flush=True))
-    observe(f"train/{name}", compile_s=run.compile_s, step_s=run.step_s,
-            losses=run.losses)
+    observe(f"train/{name}", compile_s=run.compile_s, losses=run.losses)
     if not all(math.isfinite(x) for x in run.losses):
         raise AssertionError(f"train/{name}: loss not finite: {run.losses}")
     if not run.losses[-1] < run.losses[0]:
@@ -218,7 +217,7 @@ def fsa_rounds(cfg, mesh, batch):
         for name, settings in wires.items():
             run = train(cfg, mesh, opt, settings, batch, 1, key)
             observe(f"fsa/{name}", compile_s=run.compile_s,
-                    step_s=run.step_s, loss=run.losses)
+                    loss=run.losses)
             if not math.isfinite(run.losses[0]):
                 raise AssertionError(f"fsa/{name}: loss {run.losses}")
             check_own_devices(run.params, dims, mesh, f"fsa/{name} params")

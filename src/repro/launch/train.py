@@ -72,6 +72,19 @@ from repro.models.config import ModelConfig
 from repro.optim import Optimizer, adam
 
 
+# named scopes of the round's phases.  They cover ``fsa_body`` with no
+# overlap, so every op of the compiled round carries one of them in its
+# metadata and a device trace can be split by phase.
+SCOPE_CLIENT = "eris.client"        # the client's gradient and its syncs
+SCOPE_COMPRESS = "eris.compress"    # a leaf made ready for the wire
+SCOPE_EXCHANGE = "eris.exchange"    # the client-axis collectives
+SCOPE_AGGREGATE = "eris.aggregate"  # the receive side's reduce
+SCOPE_UPDATE = "eris.update"        # the optimizer on the segment
+# host spans of ``train``: one step span a round, and the loss read in it
+SPAN_ROUND = "eris.round"
+SPAN_READ_LOSS = "eris.read_loss"
+
+
 @dataclasses.dataclass(frozen=True)
 class TrainSettings:
     grad_dtype: str = "bfloat16"     # wire dtype for the un-quantized path
@@ -226,38 +239,43 @@ def _int8_wire_exchange(v: jax.Array, dim: int, seed: jax.Array,
     from repro.kernels import quantize as q_kernel
     lay = sh.wire_layout_for(v.shape, n_client)      # the (block, scale)
     m, mp = lay.shard_elems, lay.padded_elems        # geometry on the wire
-    rows = sh.split_shards(v.astype(jnp.float32), dim, n_client)
-    rows = jnp.pad(rows, ((0, 0), (0, mp - m)))
-    q, scale = q_kernel.quantize(rows.reshape(-1), seed)
-    q = _wire_rows(q, n_client)
-    scale = scale.reshape(n_client, lay.n_blocks)
+    with jax.named_scope(SCOPE_COMPRESS):
+        rows = sh.split_shards(v.astype(jnp.float32), dim, n_client)
+        rows = jnp.pad(rows, ((0, 0), (0, mp - m)))
+        q, scale = q_kernel.quantize(rows.reshape(-1), seed)
+        q = _wire_rows(q, n_client)
+        scale = scale.reshape(n_client, lay.n_blocks)
 
-    def deq(qq, ss):
-        out = q_kernel.dequantize(qq.reshape(-1), ss.reshape(-1))
-        return out.reshape(n_client, mp)[:, :m]
+        def deq(qq, ss):
+            out = q_kernel.dequantize(qq.reshape(-1), ss.reshape(-1))
+            return out.reshape(n_client, mp)[:, :m]
 
-    v_hat = None
-    if need_round_trip:
-        v_hat = sh.merge_shards(deq(q, scale), dim, v.shape, n_client)
+        v_hat = None
+        if need_round_trip:
+            v_hat = sh.merge_shards(deq(q, scale), dim, v.shape, n_client)
     # --- the wire: int8 blocks + f32 scales cross the client axes -------
-    q_rx = jax.lax.all_to_all(q, caxis, 0, 0, tiled=True)
-    s_rx = jax.lax.all_to_all(scale, caxis, 0, 0, tiled=True)
-    rx = _wire_rows(q_kernel.dequantize(q_rx.reshape(-1), s_rx.reshape(-1)),
-                    n_client)
-    rx_rows = rx.reshape(n_client, mp)[:, :m]         # (n_client, m) views
-    if rx_w is not None:
-        # failure-injected receive: rows weighted by live links,
-        # renormalized by the live count (already folded into rx_w)
-        my = _reduce_rows(rx, m, rx_w)
-    elif omega is None:
-        my = _reduce_rows(rx, m)                      # aggregator-side sum
-    else:
-        # staleness/dropout-weighted arrivals (async buffer): each row is
-        # one client's segment, discounted by its arrival weight
-        my = _reduce_rows(rx, m, omega) / n_client
-    shard_shape = list(v.shape)
-    shard_shape[dim] //= n_client
-    return my.reshape(shard_shape), v_hat, rx_rows
+    with jax.named_scope(SCOPE_EXCHANGE):
+        q_rx = jax.lax.all_to_all(q, caxis, 0, 0, tiled=True)
+        s_rx = jax.lax.all_to_all(scale, caxis, 0, 0, tiled=True)
+    with jax.named_scope(SCOPE_AGGREGATE):
+        rx = _wire_rows(q_kernel.dequantize(q_rx.reshape(-1),
+                                            s_rx.reshape(-1)), n_client)
+        rx_rows = rx.reshape(n_client, mp)[:, :m]     # (n_client, m) views
+        if rx_w is not None:
+            # failure-injected receive: rows weighted by live links,
+            # renormalized by the live count (already folded into rx_w)
+            my = _reduce_rows(rx, m, rx_w)
+        elif omega is None:
+            my = _reduce_rows(rx, m)                  # aggregator-side sum
+        else:
+            # staleness/dropout-weighted arrivals (async buffer): each
+            # row is one client's segment, discounted by its arrival
+            # weight
+            my = _reduce_rows(rx, m, omega) / n_client
+        shard_shape = list(v.shape)
+        shard_shape[dim] //= n_client
+        my = my.reshape(shard_shape)
+    return my, v_hat, rx_rows
 
 
 def _fused_wire_exchange(g: jax.Array, s: jax.Array, dim: int,
@@ -281,26 +299,32 @@ def _fused_wire_exchange(g: jax.Array, s: jax.Array, dim: int,
     from repro.kernels import quantize as q_kernel
     lay = sh.wire_layout_for(g.shape, n_client)
     m, mp = lay.shard_elems, lay.padded_elems
-    g_rows = jnp.pad(sh.split_shards(g.astype(jnp.float32), dim, n_client),
-                     ((0, 0), (0, mp - m)))
-    s_rows = jnp.pad(sh.split_shards(s.astype(jnp.float32), dim, n_client),
-                     ((0, 0), (0, mp - m)))
-    q, scale, s_new_flat = dq_kernel.dsc_quantize(
-        g_rows.reshape(-1), s_rows.reshape(-1), seed_mask, seed_round,
-        p=p, gamma=gamma)
-    q = _wire_rows(q, n_client)
-    scale = scale.reshape(n_client, lay.n_blocks)
-    s_new = sh.merge_shards(s_new_flat.reshape(n_client, mp)[:, :m],
-                            dim, g.shape, n_client).astype(s.dtype)
+    with jax.named_scope(SCOPE_COMPRESS):
+        g_rows = jnp.pad(
+            sh.split_shards(g.astype(jnp.float32), dim, n_client),
+            ((0, 0), (0, mp - m)))
+        s_rows = jnp.pad(
+            sh.split_shards(s.astype(jnp.float32), dim, n_client),
+            ((0, 0), (0, mp - m)))
+        q, scale, s_new_flat = dq_kernel.dsc_quantize(
+            g_rows.reshape(-1), s_rows.reshape(-1), seed_mask, seed_round,
+            p=p, gamma=gamma)
+        q = _wire_rows(q, n_client)
+        scale = scale.reshape(n_client, lay.n_blocks)
+        s_new = sh.merge_shards(s_new_flat.reshape(n_client, mp)[:, :m],
+                                dim, g.shape, n_client).astype(s.dtype)
     # --- the wire: int8 blocks + f32 scales cross the client axes -------
-    q_rx = jax.lax.all_to_all(q, caxis, 0, 0, tiled=True)
-    s_rx = jax.lax.all_to_all(scale, caxis, 0, 0, tiled=True)
-    rx = _wire_rows(q_kernel.dequantize(q_rx.reshape(-1), s_rx.reshape(-1)),
-                    n_client)
-    rx_rows = rx.reshape(n_client, mp)[:, :m]
-    shard_shape = list(g.shape)
-    shard_shape[dim] //= n_client
-    return _reduce_rows(rx, m, rx_w).reshape(shard_shape), s_new, rx_rows
+    with jax.named_scope(SCOPE_EXCHANGE):
+        q_rx = jax.lax.all_to_all(q, caxis, 0, 0, tiled=True)
+        s_rx = jax.lax.all_to_all(scale, caxis, 0, 0, tiled=True)
+    with jax.named_scope(SCOPE_AGGREGATE):
+        rx = _wire_rows(q_kernel.dequantize(q_rx.reshape(-1),
+                                            s_rx.reshape(-1)), n_client)
+        rx_rows = rx.reshape(n_client, mp)[:, :m]
+        shard_shape = list(g.shape)
+        shard_shape[dim] //= n_client
+        my = _reduce_rows(rx, m, rx_w).reshape(shard_shape)
+    return my, s_new, rx_rows
 
 
 def make_train_step(cfg: ModelConfig, mesh: Mesh, opt: Optimizer,
@@ -403,80 +427,84 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, opt: Optimizer,
         buf_ref = None
         if settings.async_buffer:
             buf_ref, dsc_ref = dsc_ref["buffer"], dsc_ref["dsc"]
-        # async arrivals: the SAME ArrivalModel draw the simulator's
-        # BufferedAggregate runs, keyed on the replicated round key (no
-        # aidx fold — every mesh position must agree on who arrived)
-        arrival = async_cfg.arrival_model()
-        alive = omega = w_round = None
-        if settings.async_buffer and not arrival.trivial:
-            _, alive, omega = arrival.draw(
-                jax.random.fold_in(key, ARRIVAL_SALT), n_client)
-            w_round = omega.mean()
-        # failure injection (Appendix F.5 on the mesh): draws keyed on the
-        # replicated round key salted with the eris engine's fail role —
-        # every mesh position must agree on which aggregators/links died.
-        # link_alive is [client k, aggregator a]; a dead link zeroes k's
-        # contribution to a's segment, the aggregator renormalizes by its
-        # live-receipt count, and a dead aggregator freezes its segment.
-        # Leaves with no FSA scatter dim ride the healthy all-reduce —
-        # only the per-aggregator wire can fail.
-        agg_alive = link_alive = link_cnt = None
-        if failures:
-            ka, kl = jax.random.split(
-                jax.random.fold_in(key, ROLE_SALTS["fail"]))
-            agg_alive = jax.random.bernoulli(
-                ka, 1.0 - settings.agg_dropout, (n_client,)
-                ).astype(jnp.float32)
-            link_alive = jax.random.bernoulli(
-                kl, 1.0 - settings.link_failure, (n_client, n_client)
-                ).astype(jnp.float32)
-            link_cnt = jnp.maximum(link_alive.sum(0), 1.0)
-        if use_tp or use_pipe:
-            tp_rt = (tr.TPRuntime("model", model_size, midx_arr[0], tp_plan)
-                     if use_tp else None)
-            pipe_rt = (sp.PipeRuntime("pipe", pipe_size, pidx_arr[0],
-                                      pipe_plan) if use_pipe else None)
-            loss_val, grads = jax.value_and_grad(loss_fn)(
-                params, batch, tp_rt, pipe_rt)
-            # partial-kind leaves (replicated values consumed on local
-            # shards, e.g. qk-norm scales) sum their grads over 'model'
-            if use_tp:
-                grads = sh.tp_grad_sync(grads, tp_spec_tree, "model")
-            if use_pipe:
-                # pipe-replicated leaves (embed/head/ln_f) accumulated
-                # only where their stage touched them — sum over 'pipe';
-                # stage-sliced block leaves are already complete locally
-                grads = sh.pipe_grad_sync(grads, pipe_dim_tree, "pipe")
-            loss_val = jax.lax.pmean(loss_val, caxis)
-        else:
-            loss_val, grads = jax.value_and_grad(loss_fn)(params, batch)
-            loss_axes = (*ca, "model") if model_split else caxis
-            loss_val = jax.lax.pmean(loss_val, loss_axes)
-            if model_split:
-                # model axis = intra-group data parallelism: the group's
-                # update is the mean over its model-axis micro-shards
-                grads = jax.tree.map(lambda g: jax.lax.pmean(g, "model"),
-                                     grads)
+        with jax.named_scope(SCOPE_AGGREGATE):
+            # async arrivals: the SAME ArrivalModel draw the simulator's
+            # BufferedAggregate runs, keyed on the replicated round key (no
+            # aidx fold — every mesh position must agree on who arrived)
+            arrival = async_cfg.arrival_model()
+            alive = omega = w_round = None
+            if settings.async_buffer and not arrival.trivial:
+                _, alive, omega = arrival.draw(
+                    jax.random.fold_in(key, ARRIVAL_SALT), n_client)
+                w_round = omega.mean()
+            # failure injection (Appendix F.5 on the mesh): draws keyed on
+            # the replicated round key salted with the eris engine's fail
+            # role — every mesh position must agree on which
+            # aggregators/links died.  link_alive is [client k, aggregator
+            # a]; a dead link zeroes k's contribution to a's segment, the
+            # aggregator renormalizes by its live-receipt count, and a dead
+            # aggregator freezes its segment.  Leaves with no FSA scatter
+            # dim ride the healthy all-reduce — only the per-aggregator
+            # wire can fail.
+            agg_alive = link_alive = link_cnt = None
+            if failures:
+                ka, kl = jax.random.split(
+                    jax.random.fold_in(key, ROLE_SALTS["fail"]))
+                agg_alive = jax.random.bernoulli(
+                    ka, 1.0 - settings.agg_dropout, (n_client,)
+                    ).astype(jnp.float32)
+                link_alive = jax.random.bernoulli(
+                    kl, 1.0 - settings.link_failure, (n_client, n_client)
+                    ).astype(jnp.float32)
+                link_cnt = jnp.maximum(link_alive.sum(0), 1.0)
+        with jax.named_scope(SCOPE_CLIENT):
+            if use_tp or use_pipe:
+                tp_rt = (tr.TPRuntime("model", model_size, midx_arr[0],
+                                      tp_plan) if use_tp else None)
+                pipe_rt = (sp.PipeRuntime("pipe", pipe_size, pidx_arr[0],
+                                          pipe_plan) if use_pipe else None)
+                loss_val, grads = jax.value_and_grad(loss_fn)(
+                    params, batch, tp_rt, pipe_rt)
+                # partial-kind leaves (replicated values consumed on local
+                # shards, e.g. qk-norm scales) sum their grads over 'model'
+                if use_tp:
+                    grads = sh.tp_grad_sync(grads, tp_spec_tree, "model")
+                if use_pipe:
+                    # pipe-replicated leaves (embed/head/ln_f) accumulated
+                    # only where their stage touched them — sum over 'pipe';
+                    # stage-sliced block leaves are already complete locally
+                    grads = sh.pipe_grad_sync(grads, pipe_dim_tree, "pipe")
+                loss_val = jax.lax.pmean(loss_val, caxis)
+            else:
+                loss_val, grads = jax.value_and_grad(loss_fn)(params, batch)
+                loss_axes = (*ca, "model") if model_split else caxis
+                loss_val = jax.lax.pmean(loss_val, loss_axes)
+                if model_split:
+                    # model axis = intra-group data parallelism: the group's
+                    # update is the mean over its model-axis micro-shards
+                    grads = jax.tree.map(lambda g: jax.lax.pmean(g, "model"),
+                                         grads)
 
         leaves, treedef = jax.tree.flatten(grads)
         if ldp is not None:
-            # LDP stage (the simulator's LDPNoise, client-side on the
-            # mesh): clip this position's FULL gradient to ldp.clip in
-            # global L2, then add the calibrated Gaussian leaf-wise.
-            # Noise keys fold the eris noise-role salt + leaf index +
-            # aidx so every client draws independent noise.
-            gn2_c = sum(jnp.sum(jnp.square(l.astype(jnp.float32)))
-                        for l in leaves)
-            clip_s = jnp.minimum(
-                1.0, ldp.clip / jnp.maximum(jnp.sqrt(gn2_c), 1e-12))
-            sigma = bl.gaussian_sigma(ldp.eps, ldp.delta, ldp.clip)
-            leaves = [
-                (l.astype(jnp.float32) * clip_s
-                 + sigma * jax.random.normal(
-                     jax.random.fold_in(jax.random.fold_in(
-                         key, ROLE_SALTS["noise"] + i), aidx), l.shape)
-                 ).astype(l.dtype)
-                for i, l in enumerate(leaves)]
+            with jax.named_scope(SCOPE_COMPRESS):
+                # LDP stage (the simulator's LDPNoise, client-side on the
+                # mesh): clip this position's FULL gradient to ldp.clip in
+                # global L2, then add the calibrated Gaussian leaf-wise.
+                # Noise keys fold the eris noise-role salt + leaf index +
+                # aidx so every client draws independent noise.
+                gn2_c = sum(jnp.sum(jnp.square(l.astype(jnp.float32)))
+                            for l in leaves)
+                clip_s = jnp.minimum(
+                    1.0, ldp.clip / jnp.maximum(jnp.sqrt(gn2_c), 1e-12))
+                sigma = bl.gaussian_sigma(ldp.eps, ldp.delta, ldp.clip)
+                leaves = [
+                    (l.astype(jnp.float32) * clip_s
+                     + sigma * jax.random.normal(
+                         jax.random.fold_in(jax.random.fold_in(
+                             key, ROLE_SALTS["noise"] + i), aidx), l.shape)
+                     ).astype(l.dtype)
+                    for i, l in enumerate(leaves)]
         stage = dsc_stage(settings) if settings.use_dsc else None
         refs = (jax.tree.leaves(dsc_ref["s_clients"]) if settings.use_dsc
                 else [None] * len(leaves))
@@ -510,7 +538,8 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, opt: Optimizer,
         # 1/n_client mean of the healthy path)
         rx_w = None
         if link_alive is not None:
-            rx_w = link_alive[:, aidx] * agg_alive[aidx] / link_cnt[aidx]
+            with jax.named_scope(SCOPE_AGGREGATE):
+                rx_w = link_alive[:, aidx] * agg_alive[aidx] / link_cnt[aidx]
 
         out_leaves, refs_new, views = [], [], {}
         for i, (g, s_stk, dim) in enumerate(zip(leaves, refs, dims)):
@@ -523,57 +552,70 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, opt: Optimizer,
                 # the f32 cross-client sum; the aggregate differs from
                 # the unmasked wire only by the f32 absorption error of
                 # adding O(mask-scale) values to O(grad) values.
-                mk = jax.random.fold_in(
-                    jax.random.fold_in(key, PAIRWISE_SALT), i)
-                mrow = sa_lib.pairwise_mask_row(mk, aidx, n_client,
-                                                int(g.size))
-                g = g + mrow.reshape(g.shape).astype(g.dtype)
+                with jax.named_scope(SCOPE_COMPRESS):
+                    mk = jax.random.fold_in(
+                        jax.random.fold_in(key, PAIRWISE_SALT), i)
+                    mrow = sa_lib.pairwise_mask_row(mk, aidx, n_client,
+                                                    int(g.size))
+                    g = g + mrow.reshape(g.shape).astype(g.dtype)
             if stage is not None:
                 # client-side shifted compression (Sec. 3.2.2) — the SAME
                 # DSCCompress stage the simulator pipeline runs, leaf-wise.
                 # s_clients leaves are client-stacked (n_client, *shape),
                 # so each client-axis position holds its OWN s_k ((1,)).
-                k = jax.random.fold_in(jax.random.fold_in(key, i), aidx)
-                s = s_stk[0]
+                with jax.named_scope(SCOPE_COMPRESS):
+                    k = jax.random.fold_in(jax.random.fold_in(key, i), aidx)
+                    s = s_stk[0]
                 if int8 and settings.fused_wire:
                     # one-pass kernel: mask-draw + shift-subtract +
                     # quantize + round-trip shift update in a single VMEM
                     # sweep of the leaf (the wire payload and Eq. 4
                     # semantics are identical to the chain below)
+                    with jax.named_scope(SCOPE_COMPRESS):
+                        seeds = (jax.random.bits(k, dtype=jnp.uint32),
+                                 wire_seed(i))
                     agg, s_new, rx = _fused_wire_exchange(
-                        g, s, dim, jax.random.bits(k, dtype=jnp.uint32),
-                        wire_seed(i), caxis, n_client,
+                        g, s, dim, *seeds, caxis, n_client,
                         p=settings.dsc_p, gamma=settings.dsc_gamma,
                         rx_w=rx_w)
                     refs_new.append(s_new[None])
                     out_leaves.append(agg)
                     if capture:
-                        views[str(i)] = fail_tap(rx)[None]
+                        with jax.named_scope(SCOPE_AGGREGATE):
+                            views[str(i)] = fail_tap(rx)[None]
                     continue
                 if int8:
                     # wire format INSIDE the shifted compressor: s_k must
                     # update with what the aggregators actually receive
                     # (the simulator's Int8RoundTrip(inner=RandP)).
-                    v = stage.compressor(k, g.astype(s.dtype) - s)
+                    with jax.named_scope(SCOPE_COMPRESS):
+                        v = stage.compressor(k, g.astype(s.dtype) - s)
+                        seed = wire_seed(i)
                     agg, v_hat, rx = _int8_wire_exchange(
-                        v, dim, wire_seed(i), caxis, n_client,
+                        v, dim, seed, caxis, n_client,
                         need_round_trip=True, rx_w=rx_w)
-                    refs_new.append((s + stage.gamma * v_hat
-                                     ).astype(s.dtype)[None])
+                    with jax.named_scope(SCOPE_COMPRESS):
+                        refs_new.append((s + stage.gamma * v_hat
+                                         ).astype(s.dtype)[None])
                     out_leaves.append(agg)
                     if capture:
-                        views[str(i)] = fail_tap(rx)[None]
+                        with jax.named_scope(SCOPE_AGGREGATE):
+                            views[str(i)] = fail_tap(rx)[None]
                     continue
-                v, s_new = stage.apply_leaf(k, g, s)
-                refs_new.append(s_new[None])
-                g = v.astype(g.dtype)
+                with jax.named_scope(SCOPE_COMPRESS):
+                    v, s_new = stage.apply_leaf(k, g, s)
+                    refs_new.append(s_new[None])
+                    g = v.astype(g.dtype)
             if int8:
+                with jax.named_scope(SCOPE_COMPRESS):
+                    seed = wire_seed(i)
                 agg, _, rx = _int8_wire_exchange(
-                    g, dim, wire_seed(i), caxis, n_client,
+                    g, dim, seed, caxis, n_client,
                     need_round_trip=False, omega=omega, rx_w=rx_w)
                 out_leaves.append(agg)
                 if capture:
-                    views[str(i)] = tap(fail_tap(rx))
+                    with jax.named_scope(SCOPE_AGGREGATE):
+                        views[str(i)] = tap(fail_tap(rx))
                 continue
             # un-quantized path: reduce-scatter in grad_dtype
             if omega is not None and not (capture and dim >= 0):
@@ -581,29 +623,36 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, opt: Optimizer,
                 # own contribution BEFORE the reduce (each client-axis
                 # position is one client; the collective sums the
                 # weighted rows)
-                g = g * omega[aidx].astype(g.dtype)
-            g = g.astype(settings.grad_dtype)
+                with jax.named_scope(SCOPE_AGGREGATE):
+                    g = g * omega[aidx].astype(g.dtype)
+            with jax.named_scope(SCOPE_COMPRESS):
+                g = g.astype(settings.grad_dtype)
             if settings.fsa and dim >= 0:
                 if capture:
                     # the tap needs the PER-CLIENT segments, so the
                     # reduce-scatter lowers to its scatter half (exactly
                     # like the int8 wire) and the reduction runs
                     # aggregator-side — same result, exposed payload
-                    rows = sh.split_shards(g, dim, n_client)
-                    rx = jax.lax.all_to_all(rows, caxis, 0, 0, tiled=True)
-                    views[str(i)] = tap(fail_tap(rx)).astype(jnp.float32)
-                    shard_shape = list(g.shape)
-                    shard_shape[dim] //= n_client
-                    if rx_w is not None:
-                        agg_row = jnp.einsum("k,km->m",
-                                             rx_w.astype(rx.dtype), rx)
-                    elif omega is None:
-                        agg_row = rx.mean(0)
-                    else:
-                        agg_row = jnp.einsum(
-                            "k,km->m", omega.astype(rx.dtype), rx
-                            ) / n_client
-                    out_leaves.append(agg_row.reshape(shard_shape))
+                    with jax.named_scope(SCOPE_COMPRESS):
+                        rows = sh.split_shards(g, dim, n_client)
+                    with jax.named_scope(SCOPE_EXCHANGE):
+                        rx = jax.lax.all_to_all(rows, caxis, 0, 0,
+                                                tiled=True)
+                    with jax.named_scope(SCOPE_AGGREGATE):
+                        views[str(i)] = tap(fail_tap(rx)).astype(
+                            jnp.float32)
+                        shard_shape = list(g.shape)
+                        shard_shape[dim] //= n_client
+                        if rx_w is not None:
+                            agg_row = jnp.einsum("k,km->m",
+                                                 rx_w.astype(rx.dtype), rx)
+                        elif omega is None:
+                            agg_row = rx.mean(0)
+                        else:
+                            agg_row = jnp.einsum(
+                                "k,km->m", omega.astype(rx.dtype), rx
+                                ) / n_client
+                        out_leaves.append(agg_row.reshape(shard_shape))
                     continue
                 if link_alive is not None:
                     # failure-injected reduce-scatter: client aidx scales
@@ -611,61 +660,71 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, opt: Optimizer,
                     # collective, so the sum lands as the renormalized
                     # mean over live receipts; a dead aggregator's
                     # segment then freezes (zero update).
-                    rows = sh.split_shards(g, dim, n_client)
-                    w_l = (link_alive[aidx] / link_cnt).astype(g.dtype)
-                    g = sh.merge_shards(rows * w_l[:, None], dim, g.shape,
-                                        n_client)
-                    g = jax.lax.psum_scatter(g, caxis,
-                                             scatter_dimension=dim,
-                                             tiled=True)
-                    out_leaves.append(g * agg_alive[aidx].astype(g.dtype))
+                    with jax.named_scope(SCOPE_AGGREGATE):
+                        rows = sh.split_shards(g, dim, n_client)
+                        w_l = (link_alive[aidx] / link_cnt).astype(g.dtype)
+                        g = sh.merge_shards(rows * w_l[:, None], dim,
+                                            g.shape, n_client)
+                    with jax.named_scope(SCOPE_EXCHANGE):
+                        g = jax.lax.psum_scatter(g, caxis,
+                                                 scatter_dimension=dim,
+                                                 tiled=True)
+                    with jax.named_scope(SCOPE_AGGREGATE):
+                        out_leaves.append(
+                            g * agg_alive[aidx].astype(g.dtype))
                     continue
-                g = jax.lax.psum_scatter(g, caxis, scatter_dimension=dim,
-                                         tiled=True)
+                with jax.named_scope(SCOPE_EXCHANGE):
+                    g = jax.lax.psum_scatter(g, caxis, scatter_dimension=dim,
+                                             tiled=True)
             else:
-                g = jax.lax.psum(g, caxis)
-            out_leaves.append(g / n_client)
+                with jax.named_scope(SCOPE_EXCHANGE):
+                    g = jax.lax.psum(g, caxis)
+            with jax.named_scope(SCOPE_AGGREGATE):
+                out_leaves.append(g / n_client)
 
         grads = jax.tree.unflatten(treedef, out_leaves)
-        if settings.use_dsc:
-            # Eq. 4 aggregator-side shift compensation, on this
-            # aggregator's own segment (every term it needs is local):
-            # u = s_agg + mean_k v_k ;  s_agg <- s_agg + gamma mean_k v_k
-            # — the DSCAggregate/FSASharded(use_dsc) composition the
-            # simulator runs; without it the model update would miss the
-            # mean-shift the clients subtracted.
-            s_agg = dsc_ref["s_agg"]
-            grads = jax.tree.map(lambda s, m: s + m.astype(s.dtype),
-                                 s_agg, grads)
-            s_agg = jax.tree.map(
-                lambda s, u: s + settings.dsc_gamma * (u - s), s_agg, grads)
-            dsc_ref = {"s_clients": jax.tree.unflatten(treedef, refs_new),
-                       "s_agg": s_agg}
+        with jax.named_scope(SCOPE_AGGREGATE):
+            if settings.use_dsc:
+                # Eq. 4 aggregator-side shift compensation, on this
+                # aggregator's own segment (every term it needs is local):
+                # u = s_agg + mean_k v_k ; s_agg <- s_agg + gamma mean_k v_k
+                # — the DSCAggregate/FSASharded(use_dsc) composition the
+                # simulator runs; without it the model update would miss
+                # the mean-shift the clients subtracted.
+                s_agg = dsc_ref["s_agg"]
+                grads = jax.tree.map(lambda s, m: s + m.astype(s.dtype),
+                                     s_agg, grads)
+                s_agg = jax.tree.map(
+                    lambda s, u: s + settings.dsc_gamma * (u - s),
+                    s_agg, grads)
+                dsc_ref = {"s_clients": jax.tree.unflatten(treedef,
+                                                           refs_new),
+                           "s_agg": s_agg}
 
-        # --- FedBuff buffer fold + cadence gate (async runtime) ----------
-        do_apply = None
-        if settings.async_buffer:
-            # fold this round's arrival-weighted aggregate into the
-            # buffer; the effective update is the buffer mean on apply
-            # rounds and exactly zero in between.  Trivial arrivals +
-            # cadence 1 make every step here an IEEE-exact identity
-            # (0 + 1.0*u, u / 1.0), so the synchronous trajectory is
-            # reproduced bit-for-bit.
-            w_r = jnp.ones(()) if w_round is None else w_round
-            u_acc = jax.tree.map(
-                lambda b, g: b + w_r * g.astype(b.dtype),
-                buf_ref["u"], grads)
-            w_acc = buf_ref["w"] + w_r
-            t_new = buf_ref["t"] + 1
-            do_apply = (t_new % async_cfg.buffer_cadence) == 0
-            grads = jax.tree.map(
-                lambda u: jnp.where(do_apply,
-                                    u / jnp.maximum(w_acc, 1e-12), 0.0),
-                u_acc)
-            buf_ref = {"u": jax.tree.map(
-                           lambda u: jnp.where(do_apply, 0.0, u), u_acc),
-                       "w": jnp.where(do_apply, 0.0, w_acc),
-                       "t": t_new}
+            # --- FedBuff buffer fold + cadence gate (async runtime) ------
+            do_apply = None
+            if settings.async_buffer:
+                # fold this round's arrival-weighted aggregate into the
+                # buffer; the effective update is the buffer mean on apply
+                # rounds and exactly zero in between.  Trivial arrivals +
+                # cadence 1 make every step here an IEEE-exact identity
+                # (0 + 1.0*u, u / 1.0), so the synchronous trajectory is
+                # reproduced bit-for-bit.
+                w_r = jnp.ones(()) if w_round is None else w_round
+                u_acc = jax.tree.map(
+                    lambda b, g: b + w_r * g.astype(b.dtype),
+                    buf_ref["u"], grads)
+                w_acc = buf_ref["w"] + w_r
+                t_new = buf_ref["t"] + 1
+                do_apply = (t_new % async_cfg.buffer_cadence) == 0
+                grads = jax.tree.map(
+                    lambda u: jnp.where(do_apply,
+                                        u / jnp.maximum(w_acc, 1e-12), 0.0),
+                    u_acc)
+                buf_ref = {"u": jax.tree.map(
+                               lambda u: jnp.where(do_apply, 0.0, u), u_acc),
+                           "w": jnp.where(do_apply, 0.0, w_acc),
+                           "t": t_new}
 
         # --- shard-local optimizer on this aggregator's segment ----------
         def my_shard(p, dim):
@@ -675,49 +734,51 @@ def make_train_step(cfg: ModelConfig, mesh: Mesh, opt: Optimizer,
             return jax.lax.dynamic_slice_in_dim(p, aidx * size, size,
                                                 axis=dim)
 
-        params_shard = (jax.tree.map(my_shard, params, scatter_dims)
-                        if settings.fsa else params)
-        grads = jax.tree.map(lambda g, p: g.astype(p.dtype), grads,
-                             params_shard)
-        delta, opt_state_new = opt.update(grads, opt_state, params_shard)
-        # the update may be wider than the params (adam's f32 bias
-        # correction): params keep their dtype across steps
-        params_new = jax.tree.map(lambda p, d: (p + d).astype(p.dtype),
-                                  params_shard, delta)
-        if settings.async_buffer and async_cfg.buffer_cadence > 1:
-            # the server consumes the buffer only on cadence rounds:
-            # params and optimizer state hold still in between
-            params_new = jax.tree.map(
-                lambda a, b: jnp.where(do_apply, a, b),
-                params_new, params_shard)
-            opt_state_new = jax.tree.map(
-                lambda a, b: jnp.where(do_apply, a, b),
-                opt_state_new, opt_state)
-        params_shard, opt_state = params_new, opt_state_new
+        with jax.named_scope(SCOPE_UPDATE):
+            params_shard = (jax.tree.map(my_shard, params, scatter_dims)
+                            if settings.fsa else params)
+            grads = jax.tree.map(lambda g, p: g.astype(p.dtype), grads,
+                                 params_shard)
+            delta, opt_state_new = opt.update(grads, opt_state, params_shard)
+            # the update may be wider than the params (adam's f32 bias
+            # correction): params keep their dtype across steps
+            params_new = jax.tree.map(lambda p, d: (p + d).astype(p.dtype),
+                                      params_shard, delta)
+            if settings.async_buffer and async_cfg.buffer_cadence > 1:
+                # the server consumes the buffer only on cadence rounds:
+                # params and optimizer state hold still in between
+                params_new = jax.tree.map(
+                    lambda a, b: jnp.where(do_apply, a, b),
+                    params_new, params_shard)
+                opt_state_new = jax.tree.map(
+                    lambda a, b: jnp.where(do_apply, a, b),
+                    opt_state_new, opt_state)
+            params_shard, opt_state = params_new, opt_state_new
 
-        sq = [jnp.sum(jnp.square(g.astype(jnp.float32)))
-              for g in jax.tree.leaves(grads)]
-        if use_tp or use_pipe:
-            # TP-sharded leaves are disjoint over 'model' and block leaves
-            # disjoint over 'pipe'; replicated ones must not be
-            # double-counted by either axis sum — bucket each leaf by the
-            # axes it is actually sharded over and psum per bucket
-            tps = [s.dim >= 0 and use_tp
-                   for s in jax.tree.leaves(tp_spec_tree)]
-            pps = [pd >= 0 and use_pipe
-                   for pd in jax.tree.leaves(pipe_dim_tree)]
-            zero = jnp.zeros((), jnp.float32)
-            buckets: dict = {}
-            for x, t, pl in zip(sq, tps, pps):
-                axes = (("model",) if t else ()) + (("pipe",) if pl else ())
-                buckets[axes] = buckets.get(axes, zero) + x
-            gn2 = zero
-            for axes, tot in buckets.items():
-                gn2 = gn2 + (jax.lax.psum(tot, axes) if axes else tot)
-        else:
-            gn2 = sum(sq)
-        gnorm = jax.lax.psum(gn2, caxis) ** 0.5 \
-            if settings.fsa else jnp.sqrt(gn2)
+            sq = [jnp.sum(jnp.square(g.astype(jnp.float32)))
+                  for g in jax.tree.leaves(grads)]
+            if use_tp or use_pipe:
+                # TP-sharded leaves are disjoint over 'model' and block leaves
+                # disjoint over 'pipe'; replicated ones must not be
+                # double-counted by either axis sum — bucket each leaf by the
+                # axes it is actually sharded over and psum per bucket
+                tps = [s.dim >= 0 and use_tp
+                       for s in jax.tree.leaves(tp_spec_tree)]
+                pps = [pd >= 0 and use_pipe
+                       for pd in jax.tree.leaves(pipe_dim_tree)]
+                zero = jnp.zeros((), jnp.float32)
+                buckets: dict = {}
+                for x, t, pl in zip(sq, tps, pps):
+                    axes = ((("model",) if t else ())
+                            + (("pipe",) if pl else ()))
+                    buckets[axes] = buckets.get(axes, zero) + x
+                gn2 = zero
+                for axes, tot in buckets.items():
+                    gn2 = gn2 + (jax.lax.psum(tot, axes) if axes else tot)
+            else:
+                gn2 = sum(sq)
+            gnorm = jax.lax.psum(gn2, caxis) ** 0.5 \
+                if settings.fsa else jnp.sqrt(gn2)
         metrics = {"loss": loss_val.astype(jnp.float32), "grad_norm": gnorm}
         state_out = ({"dsc": dsc_ref, "buffer": buf_ref}
                      if settings.async_buffer else dsc_ref)
@@ -893,24 +954,27 @@ def lower_train_step(cfg: ModelConfig, mesh: Mesh,
 class TrainRun:
     """What :func:`train` returns: the final stored params, optimizer and
     DSC state, the loss of every step, and the compiled step (its text
-    shows which kernels the compiler kept).  Times are host wall clock
-    in seconds."""
+    shows which kernels the compiler kept).  ``compile_s`` is host wall
+    clock in seconds."""
     params: Any
     opt_state: Any
     dsc_ref: Any
     losses: list[float]
     compiled: Any
     compile_s: float
-    step_s: list[float]
 
 
 def train(cfg: ModelConfig, mesh: Mesh, opt: Optimizer,
           settings: TrainSettings, batch, steps: int,
-          key: Optional[jax.Array] = None, log=None) -> TrainRun:
+          key: Optional[jax.Array] = None, log=None,
+          profile_dir: Optional[str] = None) -> TrainRun:
     """Distributed FSA training on ``mesh``: parameters initialized from
     ``key`` and placed in the store layout, the step compiled once (with
     the state donated), then ``steps`` steps on the one ``batch``.
-    ``log``, if given, receives a line per step."""
+    ``log``, if given, receives a line per step.  Each step is a
+    ``SPAN_ROUND`` step span on the profiler's host clock and its loss
+    read a ``SPAN_READ_LOSS`` span; with ``profile_dir`` the steps after
+    the first are traced by ``jax.profiler`` into that directory."""
     key = jax.random.PRNGKey(0) if key is None else key
     step, shardings = make_train_step(cfg, mesh, opt, settings)
     with mesh:
@@ -921,18 +985,28 @@ def train(cfg: ModelConfig, mesh: Mesh, opt: Optimizer,
         compiled = jax.jit(step, donate_argnums=(0, 1, 2)).lower(
             params, opt_state, dsc_ref, batch, key).compile()
         compile_s = time.perf_counter() - t0
-        losses, step_s = [], []
-        for i in range(steps):
-            t0 = time.perf_counter()
-            params, opt_state, dsc_ref, m = compiled(
-                params, opt_state, dsc_ref, batch, jax.random.PRNGKey(i))
-            losses.append(float(m["loss"]))
-            step_s.append(time.perf_counter() - t0)
-            if log is not None:
-                log(f"step {i:3d} loss={losses[-1]:.4f} "
-                    f"({step_s[-1]:.2f}s)")
+        losses, tracing = [], False
+        try:
+            for i in range(steps):
+                if i == 1 and profile_dir is not None:
+                    jax.profiler.start_trace(profile_dir)
+                    tracing = True
+                t0 = time.perf_counter()
+                with jax.profiler.StepTraceAnnotation(SPAN_ROUND,
+                                                      step_num=i):
+                    params, opt_state, dsc_ref, m = compiled(
+                        params, opt_state, dsc_ref, batch,
+                        jax.random.PRNGKey(i))
+                    with jax.profiler.TraceAnnotation(SPAN_READ_LOSS):
+                        losses.append(float(m["loss"]))
+                if log is not None:
+                    log(f"step {i:3d} loss={losses[-1]:.4f} "
+                        f"({time.perf_counter() - t0:.2f}s)")
+        finally:
+            if tracing:
+                jax.profiler.stop_trace()
     return TrainRun(params, opt_state, dsc_ref, losses, compiled,
-                    compile_s, step_s)
+                    compile_s)
 
 
 def main():  # pragma: no cover - thin CLI over train()
@@ -963,6 +1037,9 @@ def main():  # pragma: no cover - thin CLI over train()
                     help="pipe axis size (contiguous layer stages)")
     ap.add_argument("--microbatches", type=int, default=1,
                     help="1F1B microbatch count (must divide --batch)")
+    ap.add_argument("--profile", default=None, metavar="DIR",
+                    help="trace the steps after the first with "
+                         "jax.profiler into DIR")
     ap.add_argument("--save", default=None, metavar="DIR",
                     help="write the final params as a sharded checkpoint "
                          "directory (the ServeEngine.from_checkpoint "
@@ -980,7 +1057,8 @@ def main():  # pragma: no cover - thin CLI over train()
     key = jax.random.PRNGKey(0)
     toks = lm_token_batches(key, 1, args.batch, args.seq, cfg.vocab)[0]
     run = train(cfg, mesh, adam(args.lr), settings, {"tokens": toks},
-                args.steps, key, log=lambda line: print(line, flush=True))
+                args.steps, key, log=lambda line: print(line, flush=True),
+                profile_dir=args.profile)
     print(f"compiled in {run.compile_s:.1f}s", flush=True)
     if args.save:
         from repro.checkpoint import msgpack_ckpt as ck

@@ -22,6 +22,14 @@ from repro.models import moe as moe_lib
 from repro.models import ssm as ssm_lib
 from repro.models.config import ModelConfig
 
+# named scopes of the model's parts: every op of a compiled step carries
+# the innermost one in its metadata, forward, backward and recomputation
+# alike, so a device trace can be split by part
+SCOPE_EMBED = "model.embed"
+SCOPE_ATTENTION = "model.attention"
+SCOPE_MLP = "model.mlp"
+SCOPE_HEAD_LOSS = "model.head_loss"
+
 
 def _ring(cfg: ModelConfig, tp) -> int:
     """Static model-axis size when ring-overlapped collectives are on
@@ -376,36 +384,43 @@ def _block(cfg: ModelConfig, lp, x, positions, mode, cache, window, tp=None):
         x, mix_state = _mlstm(cfg, lp, x, mode,
                               cache["mix"] if cache else None, tp)
         tp_ffn = tp is not None and tp.plan.ffn
-        h = L.rms_norm(x, lp["ln2"], cfg.norm_eps)
-        if tp_ffn:                      # gated in-block projection pair
-            h = L.tp_push(h, tp.axis)
-        y = _gated_mlp(h, lp["p_gate"], lp["p_up"], lp["p_down"])
-        if tp_ffn:
-            y = L.tp_pull(y, tp.axis)
-        x = x + y
+        with jax.named_scope(SCOPE_MLP):
+            h = L.rms_norm(x, lp["ln2"], cfg.norm_eps)
+            if tp_ffn:                  # gated in-block projection pair
+                h = L.tp_push(h, tp.axis)
+            y = _gated_mlp(h, lp["p_gate"], lp["p_up"], lp["p_down"])
+            if tp_ffn:
+                y = L.tp_pull(y, tp.axis)
+            x = x + y
         new_cache = {"mix": mix_state} if mode != "train" else None
         return x, new_cache, aux
     if cfg.family == "hybrid":
-        attn_out, kv = _attn(cfg, lp, x, positions, mode,
-                             cache.get("kv") if cache else None, window, tp)
+        with jax.named_scope(SCOPE_ATTENTION):
+            attn_out, kv = _attn(cfg, lp, x, positions, mode,
+                                 cache.get("kv") if cache else None,
+                                 window, tp)
         m_out, m_state = _mamba(cfg, lp, x, mode,
                                 cache.get("ssm") if cache else None, tp)
         x = 0.5 * (attn_out + (x + m_out))       # parallel heads, averaged
-        x = _ffn(cfg, lp, x, tp)
+        with jax.named_scope(SCOPE_MLP):
+            x = _ffn(cfg, lp, x, tp)
         new_cache = ({"kv": kv, "ssm": m_state} if mode != "train" else None)
         return x, new_cache, aux
     # dense / moe / audio / vlm
-    x, kv = _attn(cfg, lp, x, positions, mode,
-                  cache.get("kv") if cache else None, window, tp)
-    if cfg.family == "moe":
-        h = L.rms_norm(x, lp["ln2"], cfg.norm_eps)
-        y, aux = moe_lib.moe_ffn(h, lp["router"], lp["w_gate"], lp["w_up"],
-                                 lp["w_down"], top_k=cfg.top_k,
-                                 capacity_factor=cfg.capacity_factor,
-                                 group=cfg.moe_group_size, tp=tp)
-        x = x + y
-    else:
-        x = _ffn(cfg, lp, x, tp)
+    with jax.named_scope(SCOPE_ATTENTION):
+        x, kv = _attn(cfg, lp, x, positions, mode,
+                      cache.get("kv") if cache else None, window, tp)
+    with jax.named_scope(SCOPE_MLP):
+        if cfg.family == "moe":
+            h = L.rms_norm(x, lp["ln2"], cfg.norm_eps)
+            y, aux = moe_lib.moe_ffn(h, lp["router"], lp["w_gate"],
+                                     lp["w_up"], lp["w_down"],
+                                     top_k=cfg.top_k,
+                                     capacity_factor=cfg.capacity_factor,
+                                     group=cfg.moe_group_size, tp=tp)
+            x = x + y
+        else:
+            x = _ffn(cfg, lp, x, tp)
     new_cache = {"kv": kv} if mode != "train" else None
     return x, new_cache, aux
 
@@ -456,25 +471,27 @@ def embed_inputs(params, cfg: ModelConfig, tokens,
     [index*V/tp, (index+1)*V/tp): out-of-range tokens look up zero and
     the psum (``tp_pull``) assembles the full embedding — the backward
     stays local (each shard accumulates only its own rows' grads)."""
-    if tp is not None and tp.plan.vocab:
-        v_loc = cfg.vocab // tp.size
-        idx = tokens - tp.index * v_loc
-        ok = (idx >= 0) & (idx < v_loc)
-        x = jnp.where(ok[..., None],
-                      _embed_rows(params, cfg, jnp.clip(idx, 0, v_loc - 1)),
-                      0)
-        if tp.plan.seq:
-            # sequence-parallel residual stream: reduce-scatter the
-            # vocab partials straight into (B, S/tp, D) shards
-            x = L.tp_seq_scatter(x, tp.axis, 1)
+    with jax.named_scope(SCOPE_EMBED):
+        if tp is not None and tp.plan.vocab:
+            v_loc = cfg.vocab // tp.size
+            idx = tokens - tp.index * v_loc
+            ok = (idx >= 0) & (idx < v_loc)
+            x = jnp.where(ok[..., None],
+                          _embed_rows(params, cfg,
+                                      jnp.clip(idx, 0, v_loc - 1)),
+                          0)
+            if tp.plan.seq:
+                # sequence-parallel residual stream: reduce-scatter the
+                # vocab partials straight into (B, S/tp, D) shards
+                x = L.tp_seq_scatter(x, tp.axis, 1)
+            else:
+                x = L.tp_exit(x, tp.axis, _ring(cfg, tp))
         else:
-            x = L.tp_exit(x, tp.axis, _ring(cfg, tp))
-    else:
-        x = _embed_rows(params, cfg, tokens)
-    if cfg.frontend == "vlm":
-        assert frontend_embeds is not None
-        img = frontend_embeds.astype(x.dtype) @ params["proj_in"]
-        x = jnp.concatenate([img, x], axis=1)
+            x = _embed_rows(params, cfg, tokens)
+        if cfg.frontend == "vlm":
+            assert frontend_embeds is not None
+            img = frontend_embeds.astype(x.dtype) @ params["proj_in"]
+            x = jnp.concatenate([img, x], axis=1)
     return x
 
 
@@ -555,15 +572,16 @@ def forward(params, cfg: ModelConfig, tokens, frontend_embeds=None,
     # summed exactly once.  ln_f grads become partial (shard_plan).
     seq_ce = (tp is not None and tp.plan.seq_ce and not seq
               and mode == "train" and x.shape[1] % tp.size == 0)
-    if seq_ce:
-        x = L.ctx_enter(x, tp.axis, tp.size)
-    x = L.rms_norm(x, params["ln_f"], cfg.norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    if tp is not None and tp.plan.vocab:
-        # column-parallel unembed; a seq plan assembles the sequence here
-        x = (L.tp_seq_gather(x, tp.axis, 1) if (seq or seq_ce)
-             else L.tp_enter(x, tp.axis, _ring(cfg, tp)))
-    logits = x @ head
+    with jax.named_scope(SCOPE_HEAD_LOSS):
+        if seq_ce:
+            x = L.ctx_enter(x, tp.axis, tp.size)
+        x = L.rms_norm(x, params["ln_f"], cfg.norm_eps)
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        if tp is not None and tp.plan.vocab:
+            # column-parallel unembed; a seq plan assembles the sequence
+            x = (L.tp_seq_gather(x, tp.axis, 1) if (seq or seq_ce)
+                 else L.tp_enter(x, tp.axis, _ring(cfg, tp)))
+        logits = x @ head
     return logits, caches, {"load_balance": lb.mean()}
 
 
@@ -594,7 +612,8 @@ def loss_fn(params, cfg: ModelConfig, batch, window=None,
     logits, _, aux = forward(params, cfg, tokens,
                              batch.get("frontend_embeds"), "train", window,
                              tp=tp, inputs_embeds=batch.get("inputs_embeds"))
-    nll = _ce(cfg, logits, tokens, batch.get("loss_mask"), tp)
+    with jax.named_scope(SCOPE_HEAD_LOSS):
+        nll = _ce(cfg, logits, tokens, batch.get("loss_mask"), tp)
     if cfg.family == "moe":
         nll = nll + 0.01 * aux["load_balance"]
     return nll
