@@ -8,6 +8,7 @@ any drift in the recompute (mask bounds, GQA group sums, lse handling)
 shows up as gradient error here and nowhere else.
 """
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -15,8 +16,10 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
+from repro.kernels import flash_attention as fa
 from repro.kernels import ref
 from repro.kernels.flash_attention import flash_attention, supports
+from repro.models import layers
 from repro.models import transformer as tr
 
 KEY = jax.random.PRNGKey(0)
@@ -30,13 +33,17 @@ def _qkv(B, H, KV, S, d, seed=0):
     return q, k, v
 
 
-def _grad_parity(B, H, KV, S, d, *, causal, window, bq=64, bk=64):
+def _grad_parity(B, H, KV, S, d, *, causal, window, bq=64, bk=64,
+                 plan=None):
     q, k, v = _qkv(B, H, KV, S, d)
     w = jax.random.normal(jax.random.fold_in(KEY, 9), (B, H, S, d))
 
     def loss_flash(q, k, v):
-        o = flash_attention(q, k, v, causal=causal, window=window,
-                            block_q=bq, block_k=bk, interpret=True)
+        if plan is None:
+            o = flash_attention(q, k, v, causal=causal, window=window,
+                                block_q=bq, block_k=bk, interpret=True)
+        else:
+            o = fa._flash(q, k, v, (causal, window, plan, True))
         return jnp.sum(o * w)
 
     def loss_ref(q, k, v):
@@ -73,10 +80,123 @@ def test_flash_backward_uneven_blocks():
     _grad_parity(1, 2, 2, 256, 32, causal=True, window=64, bq=64, bk=128)
 
 
+# a GQA group split over several steps: the forward / dq tiles stack
+# part of the group, and the dk/dv grid's last axis walks the group in
+# chunks summed in its f32 scratch
+@pytest.mark.parametrize("heads,heads_dkv", [(2, 1), (1, 2), (4, 4)])
+@pytest.mark.parametrize("window", [None, 48])
+def test_flash_backward_head_chunks(heads, heads_dkv, window):
+    plan = fa.Tiles(block_q=64, block_k=32, heads=heads,
+                    heads_dkv=heads_dkv, buffers=1)
+    _grad_parity(1, 8, 2, 128, 32, causal=True, window=window, plan=plan)
+
+
 def test_supports_gate():
     assert supports(128, 64) and supports(1024, 64)
     assert supports(64, 32)          # blocks clamp to S
     assert not supports(192, 32)     # 192 % min(128, 192) != 0
+    assert supports(256, 32, block_q=64, block_k=128)
+    assert not supports(256, 32, block_q=96)
+    # grouped-query heads: qwen2-0.5b's 7 query heads per kv head get a
+    # plan exactly where the gate admits, at the lengths the model trains
+    # and up to the longest the gate admits
+    for S in (1024, 4096, 8192, 16384, 32768):
+        assert supports(S, 64, itemsize=2) == (S <= 16384)
+        assert (fa.tiles(S, 64, 7, 2) is not None) == supports(S, 64, 2)
+    assert fa.tiles(64, 32, 4, 4) is not None
+    assert fa.tiles(192, 32, 7, 4) is None
+
+
+def _old_gate(S, d, itemsize):
+    """What the gate admitted before heads were stacked: both block
+    sizes tile S, and K/V (or q/dO) double-buffered fit 12 MiB."""
+    return (S % min(128, S) == 0
+            and 2 * 2 * S * max(d, 128) * itemsize <= 12 * 1024 * 1024)
+
+
+@pytest.mark.parametrize("itemsize", [2, 4])
+@pytest.mark.parametrize("G", [1, 2, 4, 7, 8, 16, 32])
+def test_tiles_fit_vmem_and_tile_the_sequence(G, itemsize):
+    """The tile chooser, a pure function of (S, d, G, itemsize): every
+    plan tiles S and the group exactly and fits the VMEM budget, and
+    every shape the gate admitted before is still admitted."""
+    for S in (32, 64, 128, 192, 256, 384, 512, 640, 1024, 1536, 2048,
+              3072, 4096, 6144, 8192, 12288, 16384, 32768):
+        for d in (32, 64, 96, 128):
+            t = fa.tiles(S, d, G, itemsize)
+            assert t == fa.tiles(S, d, G, itemsize)
+            assert supports(S, d, itemsize) == (t is not None)
+            if _old_gate(S, d, itemsize):
+                assert t is not None, (S, d, G, itemsize)
+            if t is None:
+                continue
+            assert S % t.block_q == 0 and S % t.block_k == 0, t
+            assert G % t.heads == 0 and G % t.heads_dkv == 0, t
+            assert t.buffers in (1, 2)
+            assert fa._vmem_bytes(S, d, itemsize, t) <= fa.WHOLE_SEQ_VMEM
+
+
+def test_tiles_of_the_benchmarked_shapes():
+    """qwen2-0.5b (7 query heads per kv head, d 64) stacks its whole
+    group in every step; phi3-mini (one query head per kv head, d 96)
+    gets a block above 128 rows instead."""
+    qwen2 = fa.tiles(1024, 64, 7, 2)
+    assert qwen2.heads == qwen2.heads_dkv == 7
+    assert qwen2.heads * qwen2.block_q > 7 * 128
+    phi3 = fa.tiles(1024, 96, 1, 2)
+    assert max(phi3.block_q, phi3.block_k) > 128
+
+
+def _rel_err(x, expect):
+    x = np.asarray(x, np.float64)
+    expect = np.asarray(expect, np.float64)
+    return np.linalg.norm(x - expect) / np.linalg.norm(expect)
+
+
+@functools.lru_cache(maxsize=None)
+def _bf16_errors(H, KV, d):
+    """{o, dq, dk, dv: (kernel error, model XLA attention error)}, each
+    a relative error against the f32 reference on the upcast bf16
+    inputs."""
+    B, S = 1, 256
+    q, k, v = (x.astype(jnp.bfloat16) for x in _qkv(B, H, KV, S, d, seed=3))
+    w = jax.random.normal(jax.random.fold_in(KEY, 7), (B, H, S, d))
+
+    def kernel(q, k, v):
+        return flash_attention(q, k, v, causal=True, interpret=True)
+
+    def model_xla(q, k, v):
+        return layers.causal_attention(
+            q.swapaxes(1, 2), k.swapaxes(1, 2), v.swapaxes(1, 2)
+        ).swapaxes(1, 2)
+
+    def reference(q, k, v):
+        return ref.flash_attention_ref(q, k, v, causal=True)
+
+    def outputs(attend, q, k, v):
+        def loss(q, k, v):
+            return jnp.sum(attend(q, k, v).astype(jnp.float32) * w)
+        grads = jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+        return (attend(q, k, v),) + grads
+
+    f32 = [x.astype(jnp.float32) for x in (q, k, v)]
+    exp = outputs(reference, *f32)
+    got = outputs(kernel, q, k, v)
+    xla = outputs(model_xla, q, k, v)
+    return {name: (_rel_err(g, e), _rel_err(x, e))
+            for name, g, x, e in zip(("o", "dq", "dk", "dv"), got, xla, exp)}
+
+
+# bf16 at qwen2-0.5b's group shape and phi3-mini's (H == KV, d 96): the
+# kernel's error against the f32 reference is held to that of the
+# model's own bf16 XLA attention on the same inputs, so the tolerance is
+# the model's stated precision, not a chosen constant
+@pytest.mark.parametrize("quantity", ["o", "dq", "dk", "dv"])
+@pytest.mark.parametrize("H,KV,d", [(14, 2, 64), (4, 4, 96)],
+                         ids=["qwen2-group", "phi3-heads"])
+def test_flash_bf16_error_within_model_attention(H, KV, d, quantity):
+    kernel_err, xla_err = _bf16_errors(H, KV, d)[quantity]
+    assert kernel_err <= 1.5 * xla_err, (quantity, kernel_err, xla_err)
 
 
 def test_model_train_grads_flash_vs_naive():

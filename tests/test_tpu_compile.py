@@ -11,8 +11,10 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker
 imports this file.
 """
+import base64
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -96,12 +98,17 @@ def test_dsc_kernels_compile(one_chip, kernel):
                  g, g, seed, seed)
 
 
-def _flash_grad(sharding, seq):
-    B, H, KV, d = 8, CFG.n_heads, CFG.n_kv_heads, CFG.hd
+def _flash_grad(sharding, seq, heads=(CFG.n_heads, CFG.n_kv_heads),
+                d=CFG.hd, plan=None):
+    B, (H, KV) = 8, heads
+
+    def attend(q, k, v):
+        if plan is None:
+            return fa.flash_attention(q, k, v, causal=True, interpret=False)
+        return fa._flash(q, k, v, (True, None, plan, False))
 
     def loss(q, k, v):
-        return fa.flash_attention(q, k, v, causal=True,
-                                  interpret=False).astype(jnp.float32).sum()
+        return attend(q, k, v).astype(jnp.float32).sum()
 
     q = _spec(sharding, (B, H, seq, d), jnp.bfloat16)
     kv = _spec(sharding, (B, KV, seq, d), jnp.bfloat16)
@@ -109,21 +116,50 @@ def _flash_grad(sharding, seq):
                              q, kv, kv)
 
 
-@pytest.mark.parametrize("seq", [1024, 4096, 8192])
+@pytest.mark.parametrize("seq", [1024, 4096, 8192, 16384])
 def test_flash_forward_backward_compile(one_chip, seq):
     """Forward and custom-VJP backward at the train_1k / train_4k
-    sequence lengths and the longest the gate admits, qwen2-0.5b's
+    sequence lengths up to the longest the gate admits, qwen2-0.5b's
     heads, bf16 activations."""
     assert fa.supports(seq, CFG.hd, itemsize=2)
     _flash_grad(one_chip, seq)()
 
 
+def test_flash_compile_full_multi_head_width(one_chip):
+    """Phi-3-mini's attention (32 heads, each its own kv head, head_dim
+    96): one head per tile, so the tiles grow along the sequence."""
+    assert fa.tiles(1024, 96, 1, 2).block_q > 128
+    _flash_grad(one_chip, 1024, (32, 32), 96)()
+
+
 def test_flash_supports_refuses_what_vmem_cannot_hold(one_chip):
     """The kernels hold whole-sequence operands in fast memory; the gate
-    refuses the first sequence length the chip's compiler cannot fit."""
-    assert not fa.supports(16384, CFG.hd, itemsize=2)
+    refuses the first sequence length the chip's compiler cannot fit,
+    even with the smallest tiles."""
+    assert not fa.supports(32768, CFG.hd, itemsize=2)
+    smallest = fa.Tiles(block_q=128, block_k=128, heads=1, heads_dkv=1,
+                        buffers=1)
     with pytest.raises(Exception, match="vmem"):
-        _flash_grad(one_chip, 16384)()
+        _flash_grad(one_chip, 32768, plan=smallest)()
+
+
+def test_flash_compiles_to_the_three_named_kernels(one_chip):
+    """Forward + backward at qwen2-0.5b's shapes is exactly three Pallas
+    calls whose Mosaic bodies name the forward, dq and dk/dv kernels, as
+    the benchmark's ``bench/hlo.py`` reads them, and this file.  (The
+    source file the reader puts before the name is the first one a body
+    lists, which depends on the caller: the benchmark's round reads
+    ``flash_attention``.)"""
+    from bench.hlo import pallas_kernels
+    text = _flash_grad(one_chip, 1024)().as_text()
+    kernels = pallas_kernels(text).values()
+    assert sorted(k.rsplit(".", 1)[1] for k in kernels) == [
+        "_dkv_kernel", "_dq_kernel", "_fwd_kernel"]
+    bodies = [line for line in text.splitlines()
+              if "tpu_custom_call" in line and "=" in line]
+    assert len(bodies) == 3
+    assert all(b"flash_attention.py" in base64.b64decode(
+        re.search(r'"body":"([^"]*)"', line).group(1)) for line in bodies)
 
 
 def test_paged_attention_compile(one_chip):
